@@ -47,7 +47,9 @@ def main() -> None:
         "roofline": roofline.run,
     }
     from benchmarks import common
+    from repro.utils import enable_compile_cache
 
+    enable_compile_cache()
     wanted = sys.argv[1:] or list(benches)
     print("name,us_per_call,derived")
     failures = 0

@@ -7,14 +7,18 @@ Phase 1 trains on a single device and checkpoints. Phase 2 re-launches in a
 subprocess with 8 forced host devices, restores the same checkpoint onto a
 (2, 4) mesh (the CheckpointManager re-shards arrays with jax.device_put
 against the new NamedShardings), and continues training — the loss picks up
-where it left off.
+where it left off. Both phases run on the CPU backend (phase 2's eight
+devices are host-forced), so the demo never holds an accelerator that its
+child process would then need.
 """
+import os
 import pathlib
 import shutil
 import subprocess
 import sys
 import textwrap
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT / "src"))
 
@@ -23,6 +27,7 @@ CKPT = ROOT / "experiments" / "ckpt" / "elastic_demo"
 PHASE2 = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"
 import sys
 sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp

@@ -295,24 +295,24 @@ CHUNK_ENCODERS.register(
 
 
 @CHUNK_ENCODERS.register("pallas", preferred_backend="tpu",
-                         doc="fused mbcodec tile (TPU); jnp tile off-TPU")
+                         doc="fused mbcodec tile (TPU); exact scan off-TPU")
 def encode_chunk_pallas(frames: jnp.ndarray, qp_maps: jnp.ndarray):
     """Chunk encoder backed by the fused ``kernels/mbcodec`` tile.
 
     Per frame, ``kernels.mbcodec.ops.encode_frame_fused`` runs
     blockify-DCT-quant-dequant-IDCT + the entropy bits in one VMEM
-    round-trip (Pallas on TPU; the jnp reference tile elsewhere — the
-    off-TPU fallback is automatic, selected at trace time). P-frames code
-    the residual against the previous *decoded* frame exactly like
-    :func:`encode_chunk` (same :func:`_scan_chunk` scaffold), so output is
-    bit-comparable to ``impl="exact"``.
+    round-trip. P-frames code the residual against the previous *decoded*
+    frame exactly like :func:`encode_chunk` (same :func:`_scan_chunk`
+    scaffold), so output is bit-comparable to ``impl="exact"``. Off-TPU
+    the backend substitutes :func:`encode_chunk` itself (selected at
+    trace time, announced once by ``warn_fallback``).
     """
     from repro.kernels.mbcodec.ops import encode_frame_fused, on_tpu, \
         warn_fallback
 
     if not on_tpu():
-        warn_fallback("pallas", "the jnp reference tile (mbcodec_ref), "
-                      "scanned per frame")
+        warn_fallback("pallas", "the exact scan (codec.encode_chunk)")
+        return encode_chunk(frames, qp_maps)
     return _scan_chunk(
         lambda f, q, ref: encode_frame_fused(f, q, reference=ref),
         frames, qp_maps)

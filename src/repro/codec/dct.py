@@ -3,9 +3,14 @@
 H.264 applies 4x4/8x8 integer transforms inside 16x16 macroblocks — shapes
 hostile to a 128x128 MXU. We lift the transform to a single 16x16 DCT-II
 per macroblock expressed as two dense matmuls ``D @ X @ D.T`` and batch
-macroblocks along the leading dim so the MXU sees large GEMMs
-(DESIGN.md §5). The codec is therefore H.264-*shaped* (QP semantics,
+macroblocks along the leading dim so the MXU sees large GEMMs (the Pallas
+codec kernel runs the same transform as one Kronecker matmul, see
+:func:`dct_kron`). The codec is therefore H.264-*shaped* (QP semantics,
 macroblock RoI, I/P frames), not bit-exact H.264.
+
+Transforms run at ``Precision.HIGHEST``: on the TPU an f32 matmul
+otherwise takes a single bf16 pass, and the coefficients are rounded to
+integers right after, so the chip must compute the CPU's f32 transform.
 """
 from __future__ import annotations
 
@@ -26,6 +31,15 @@ def dct_matrix(n: int = MB) -> np.ndarray:
     d = np.cos(np.pi * (2 * i + 1) * k / (2 * n)) * np.sqrt(2.0 / n)
     d[0] /= np.sqrt(2.0)
     return d.astype(np.float32)
+
+
+@functools.lru_cache()
+def dct_kron(n: int = MB) -> np.ndarray:
+    """``kron(D, D)`` (n*n x n*n): the 2-D DCT of a row-major flattened
+    block, ``dct_kron() @ x.reshape(-1) == (D @ x @ D.T).reshape(-1)``;
+    its transpose is the inverse transform."""
+    d = dct_matrix(n)
+    return np.kron(d, d).astype(np.float32)
 
 
 @functools.lru_cache()
@@ -53,12 +67,14 @@ def unblockify(blocks: jnp.ndarray, H: int, W: int) -> jnp.ndarray:
 def dct2(blocks: jnp.ndarray) -> jnp.ndarray:
     """blocks (..., 16, 16) -> coefficients."""
     d = jnp.asarray(dct_matrix())
-    return jnp.einsum("ij,...jk,lk->...il", d, blocks, d)
+    return jnp.einsum("ij,...jk,lk->...il", d, blocks, d,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def idct2(coefs: jnp.ndarray) -> jnp.ndarray:
     d = jnp.asarray(dct_matrix())
-    return jnp.einsum("ji,...jk,kl->...il", d, coefs, d)
+    return jnp.einsum("ji,...jk,kl->...il", d, coefs, d,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def qstep(qp) -> jnp.ndarray:

@@ -48,10 +48,7 @@ def compressed_psum(grads, axis_name: str, method: str = "int8"):
     bf16: cast to bf16 before the reduction (2x bytes saving)
     none: plain psum
     """
-    # axis_size landed after 0.4.x; psum of a literal constant-folds to the
-    # axis size as a Python int on every version
-    n = (jax.lax.axis_size(axis_name) if hasattr(jax.lax, "axis_size")
-         else jax.lax.psum(1, axis_name))
+    n = jax.lax.axis_size(axis_name)
 
     if method == "none" or n == 1:
         return tree_map(lambda g: jax.lax.psum(g, axis_name), grads)

@@ -36,7 +36,6 @@ from typing import Any, List
 
 import jax
 
-from repro.distributed.sharding import process_count, process_index
 
 #: environment contract with ``repro.launch.fleet`` (and any external
 #: process manager: k8s pod env, mpirun wrapper, ...)
@@ -60,7 +59,7 @@ def init_from_env() -> bool:
 
 
 def is_distributed() -> bool:
-    return process_count() > 1
+    return jax.process_count() > 1
 
 
 class LocalExchange:
@@ -129,8 +128,8 @@ class KVExchange:
                 "repro.launch.fleet launcher)")
         self._client = client
         self.timeout_ms = int(timeout_s * 1000)
-        self.host = process_index()
-        self.n_hosts = process_count()
+        self.host = jax.process_index()
+        self.n_hosts = jax.process_count()
         # hosts marked dead (explicitly or by a tolerant gather timing
         # out): all subsequent gathers skip them, so survivors stay in
         # lockstep with each other rather than blocking on a corpse

@@ -26,22 +26,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def process_count() -> int:
-    """Version-tolerant ``jax.process_count()`` (1 on ancient jax or a
-    backend that is not yet initialized)."""
-    try:
-        return int(jax.process_count())
-    except Exception:
-        return 1
-
-
-def process_index() -> int:
-    try:
-        return int(jax.process_index())
-    except Exception:
-        return 0
-
-
 def host_local_devices():
     """The devices fleet-serving meshes may use in this process.
 
@@ -52,7 +36,7 @@ def host_local_devices():
     ``jax.local_devices()``. Single-process, local == global and nothing
     changes for existing callers.
     """
-    return jax.local_devices() if process_count() > 1 else jax.devices()
+    return jax.local_devices() if jax.process_count() > 1 else jax.devices()
 
 
 def assert_addressable_mesh(mesh: Mesh, what: str) -> None:
@@ -61,33 +45,23 @@ def assert_addressable_mesh(mesh: Mesh, what: str) -> None:
     by design; silently lowering over a global mesh would hang or
     mis-shard. Multi-host serving goes through
     ``repro.serve.fleet.serve_fleet`` instead."""
-    pid = process_index()
+    pid = jax.process_index()
     remote = [d for d in np.asarray(mesh.devices).flat
               if getattr(d, "process_index", pid) != pid]
     if remote:
         raise ValueError(
             f"{what} is host-local but the mesh names "
             f"{len(remote)} device(s) owned by other processes "
-            f"(process {pid} of {process_count()}); build fleet meshes "
+            f"(process {pid} of {jax.process_count()}); build fleet meshes "
             f"over jax.local_devices() (distributed.mesh helpers do) and "
             f"use repro.serve.fleet.serve_fleet for multi-host serving")
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-spanning shard_map with replication checking off.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=False)``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=False)``. The
-    sharded fleet-serving path (and the distributed tests) go through this
-    one shim so the rest of the tree never version-switches.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication checking off: the one call the
+    sharded fleet-serving path (and the distributed tests) go through."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,31 +1,39 @@
 """Fused macroblock codec Pallas kernels (TPU target).
 
+Layout: macroblocks ride the lane axis. A tile is ``(256, TILE)``: each
+column is one 16x16 macroblock channel flattened row-major, so every
+operand is lane-dense, (8, 128)-aligned, and 4 B x 256 x TILE per buffer.
+The separable 16x16 DCT ``D @ X @ D.T`` becomes one Kronecker matmul
+``kron(D, D) @ x`` (and the inverse ``kron(D, D).T @ c``): a (256, 256) x
+(256, TILE) contraction the MXU runs at full width, with no in-kernel
+transposes or rank-changing reshapes. Per-macroblock operands (QP,
+pooled scores, bits) are ``(1, TILE)`` rows that broadcast along
+sublanes; the per-coefficient frequency weight is a ``(256, 1)`` column
+that broadcasts along lanes. Matmuls run at ``Precision.HIGHEST`` so the
+chip computes the f32 transform the CPU does.
+
 Two kernel generations live here:
 
-* ``mbcodec_pallas`` — the original per-frame tile: one VMEM round-trip
-  does blockify-DCT-quant-dequant-IDCT + the entropy-bit estimate, with
-  the per-macroblock QP prefetched alongside the tile. TPU adaptation
-  (DESIGN.md §5): macroblocks are batched along the leading dim so the
-  two 16x16 transform matmuls run as (TILE*16, 16) x (16, 16) GEMMs —
-  the 16-contraction is the only small dim the MXU sees.
+* ``mbcodec_pallas`` — the per-frame tile: transform, quantize, entropy
+  bits, dequantize, inverse transform in one VMEM round-trip, with the
+  per-macroblock QP fetched alongside the tile.
 
 * ``mbcodec_chunk_pallas`` / ``mbcodec_chunk_scores_pallas`` — the fused
   camera fast-path (the registry's ``fused`` / ``fused_exact`` backends).
-  Grid ``(n_tiles, T)`` with the frame axis innermost and sequential (the
-  ``wkv6`` grid-carry idiom): each tile's decoded P-frame reference lives
-  in VMEM scratch across the whole chunk scan, so quantize → bits →
-  reconstruct for frame t+1 reads frame t's reference without an HBM
-  round-trip. Pallas pipelines the per-step block DMA against compute
-  automatically, which double-buffers the frame fetch across the scan —
-  while frame t's tile is in the MXU, frame t+1's tile is in flight.
-  The ``scores`` variant additionally takes the dilated AccModel score
-  map plus the (alpha, qp_hi, qp_lo) knob triple and assigns the
-  two-level QP *inside* the kernel, so no QP map ever materializes in
-  HBM between scoring and encode.
+  Grid ``(n_tiles, T)`` with the frame axis innermost and sequential:
+  each tile's P-frame reference lives in VMEM scratch across the whole
+  chunk scan, so frame t+1 codes against frame t's reference without an
+  HBM round-trip, while Pallas double-buffers the next frame's tile DMA
+  against compute. ``fused`` carries the reference as unclipped DCT
+  coefficients (``codec.encode_chunk_fast``'s recursion), ``fused_exact``
+  as clipped pixels (``codec.encode_chunk``'s). The ``scores`` variant takes the
+  dilated AccModel score row plus the (alpha, qp_hi, qp_lo) knob triple
+  (in SMEM) and assigns the two-level QP inside the kernel, so no QP map
+  ever materializes in HBM between scoring and encode.
 
 Validated against ref.mbcodec_ref / codec.encode_chunk in interpret mode
-(tests/test_kernels.py); on CPU hosts ops.py always selects interpret or
-the jnp path.
+(tests/test_kernels.py) and compiled for a described v5e in
+tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -33,144 +41,165 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.codec.codec import BITS_PER_MAG, BLOCK_OVERHEAD, RUN_BITS
-from repro.codec.dct import dct_matrix, freq_weight
+from repro.codec.dct import dct_kron, freq_weight
 
-TILE = 64  # macroblocks per VMEM tile: 64*16*16*4B = 64 KiB per buffer
+TILE = 256  # macroblock channels per tile (lanes): 256 KiB per buffer
+COEFS = 256  # 16 x 16 coefficients per macroblock channel (sublanes)
+_HI = jax.lax.Precision.HIGHEST
+
+_SQ = pl.Squeezed()
 
 
-def _kernel(blocks_ref, qp_ref, d_ref, w_ref, rec_ref, bits_ref):
-    x = blocks_ref[...]  # (TILE, 16, 16)
-    qp = qp_ref[...]     # (TILE,)
-    d = d_ref[...]       # (16, 16) DCT matrix (broadcast to every tile)
-    dt = d.T
-    w = w_ref[...]
-    # DCT: D @ X @ D^T as two batched GEMMs
-    c = jax.lax.dot_general(x, d, (((2,), (1,)), ((), ())))          # X @ D^T -> (T,16,16)
-    c = jax.lax.dot_general(c, d, (((1,), (1,)), ((), ())))          # (T,16k,16i)?
-    # dot_general above contracts axis1 with d's axis1: result (T, 16, 16)
-    # with transform rows in the LAST dim; transpose back
-    c = c.transpose(0, 2, 1)
-    step = (0.625 * jnp.exp2((qp - 4.0) / 6.0) / 255.0)[:, None, None] * w
-    q = jnp.round(c / step)
+def _qstep(qp, w):
+    """(1, TILE) QP row x (256, 1) weight column -> (256, TILE) steps
+    (``codec.dct.qstep`` times ``freq_weight``)."""
+    return (0.625 * jnp.exp2((qp - 4.0) / 6.0) / 255.0) * w
+
+
+def _bits(q):
+    """(256, TILE) quantized coefficients -> (1, TILE) entropy bits
+    (``codec.block_bits`` per channel column)."""
     aq = jnp.abs(q)
-    bits = (BITS_PER_MAG * jnp.log2(1.0 + aq)
-            + RUN_BITS * (aq > 0.5).astype(jnp.float32)).sum(axis=(1, 2)) \
-        + BLOCK_OVERHEAD
-    deq = q * step
-    # IDCT: D^T @ C @ D
-    r = jax.lax.dot_general(deq, dt, (((2,), (1,)), ((), ())))
-    r = jax.lax.dot_general(r, dt, (((1,), (1,)), ((), ()))).transpose(0, 2, 1)
-    rec_ref[...] = r
+    return (BITS_PER_MAG * jnp.log2(1.0 + aq)
+            + RUN_BITS * (aq > 0.5).astype(jnp.float32)).sum(
+                axis=0, keepdims=True) + BLOCK_OVERHEAD
+
+
+def _fwd(kf, x):
+    return jax.lax.dot_general(kf, x, (((1,), (0,)), ((), ())),
+                               precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _inv(kf, c):
+    return jax.lax.dot_general(kf, c, (((0,), (0,)), ((), ())),
+                               precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _encode_tile_step(x, ref, qp, kf, w):
+    """One pixel-space encode step for a (256, TILE) tile, VMEM-resident
+    (``codec.encode_frame``'s arithmetic; ``ref.mbcodec_ref`` spells out
+    the same order).
+
+    ``ref`` is the tile's decoded reference (zeros for an I-frame);
+    ``qp`` is the (1, TILE) per-macroblock QP row, ``kf`` the (256, 256)
+    Kronecker DCT and ``w`` the (256, 1) frequency-weight column. Returns
+    the unclipped decoded tile and the (1, TILE) entropy bits.
+    """
+    step = _qstep(qp, w)
+    q = jnp.round(_fwd(kf, x - ref) / step)
+    return ref + _inv(kf, q * step), _bits(q)
+
+
+def _encode_coef_step(x, rec, qp, kf, w):
+    """One coefficient-space P-frame step (``codec.encode_chunk_fast``'s
+    arithmetic): ``rec`` is the reference in DCT coefficients, never
+    clipped between frames. Returns (new coefficient reference, decoded
+    tile, bits)."""
+    step = _qstep(qp, w)
+    q = jnp.round((_fwd(kf, x) - rec) * (1.0 / step))
+    rec = rec + q * step
+    return rec, _inv(kf, rec), _bits(q)
+
+
+def _kernel(blocks_ref, qp_ref, kf_ref, w_ref, rec_ref, bits_ref):
+    x = blocks_ref[...]
+    rec, bits = _encode_tile_step(x, jnp.zeros_like(x), qp_ref[...],
+                                  kf_ref[...], w_ref[...])
+    rec_ref[...] = rec
     bits_ref[...] = bits
 
 
-def _encode_tile_step(x, ref, qp, d, w, clip_refs: bool):
-    """One P-frame encode step for a (TILE, 16, 16) tile, VMEM-resident.
-
-    ``ref`` is the tile's decoded reference from the previous frame
-    (zeros at the chunk head -> I-frame). Returns the new decoded tile
-    and per-block entropy bits. ``clip_refs`` statically selects the
-    exact encoder's per-step [0, 1] reference clip (``fused_exact``)
-    versus the fast path's decode-time-only clip (``fused``).
-    """
-    dt = d.T
-    src = x - ref
-    c = jax.lax.dot_general(src, d, (((2,), (1,)), ((), ())))
-    c = jax.lax.dot_general(c, d, (((1,), (1,)), ((), ()))).transpose(0, 2, 1)
-    step = (0.625 * jnp.exp2((qp - 4.0) / 6.0) / 255.0)[:, None, None] * w
-    q = jnp.round(c / step)
-    aq = jnp.abs(q)
-    bits = (BITS_PER_MAG * jnp.log2(1.0 + aq)
-            + RUN_BITS * (aq > 0.5).astype(jnp.float32)).sum(axis=(1, 2)) \
-        + BLOCK_OVERHEAD
-    deq = q * step
-    r = jax.lax.dot_general(deq, dt, (((2,), (1,)), ((), ())))
-    r = jax.lax.dot_general(r, dt, (((1,), (1,)), ((), ()))).transpose(0, 2, 1)
-    rec = ref + r
-    if clip_refs:
-        rec = jnp.clip(rec, 0.0, 1.0)
-    return rec, bits
-
-
-def _chunk_kernel(clip_refs, blocks_ref, qp_ref, d_ref, w_ref,
-                  rec_ref, bits_ref, ref_scr):
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
+def _chunk_step(clip_refs, x, qp, kf_ref, w_ref, rec_ref, bits_ref,
+                ref_scr):
+    """Shared chunk-scan body: the scratch carries the reference across
+    the frame axis — clipped pixels for ``fused_exact`` (the exact
+    encoder), unclipped coefficients for ``fused`` (the fast encoder)."""
+    @pl.when(pl.program_id(1) == 0)
     def _init():  # chunk head: I-frame against a zero reference
         ref_scr[...] = jnp.zeros_like(ref_scr)
 
-    rec, bits = _encode_tile_step(blocks_ref[0], ref_scr[...], qp_ref[0],
-                                  d_ref[...], w_ref[...], clip_refs)
-    ref_scr[...] = rec
-    rec_ref[0] = rec
-    bits_ref[0] = bits
+    if clip_refs:
+        rec, bits = _encode_tile_step(x, ref_scr[...], qp, kf_ref[...],
+                                      w_ref[...])
+        dec = ref_scr[...] = jnp.clip(rec, 0.0, 1.0)
+    else:
+        state, dec, bits = _encode_coef_step(x, ref_scr[...], qp,
+                                             kf_ref[...], w_ref[...])
+        ref_scr[...] = state
+    rec_ref[...] = dec
+    bits_ref[...] = bits
+
+
+def _chunk_kernel(clip_refs, blocks_ref, qp_ref, kf_ref, w_ref,
+                  rec_ref, bits_ref, ref_scr):
+    _chunk_step(clip_refs, blocks_ref[...], qp_ref[...], kf_ref, w_ref,
+                rec_ref, bits_ref, ref_scr)
 
 
 def _chunk_scores_kernel(clip_refs, blocks_ref, pooled_ref, knobs_ref,
-                         d_ref, w_ref, rec_ref, bits_ref, ref_scr):
-    t = pl.program_id(1)
+                         kf_ref, w_ref, rec_ref, bits_ref, ref_scr):
+    # knobs (SMEM): alpha, qp_hi, qp_lo (traced controller knobs)
+    qp = jnp.where(pooled_ref[...] >= knobs_ref[0], knobs_ref[1],
+                   knobs_ref[2])
+    _chunk_step(clip_refs, blocks_ref[...], qp, kf_ref, w_ref, rec_ref,
+                bits_ref, ref_scr)
 
-    @pl.when(t == 0)
-    def _init():
-        ref_scr[...] = jnp.zeros_like(ref_scr)
 
-    kn = knobs_ref[...]  # (3,): alpha, qp_hi, qp_lo (traced controller knobs)
-    qp = jnp.where(pooled_ref[...] >= kn[0], kn[1], kn[2])
-    rec, bits = _encode_tile_step(blocks_ref[0], ref_scr[...], qp,
-                                  d_ref[...], w_ref[...], clip_refs)
-    ref_scr[...] = rec
-    rec_ref[0] = rec
-    bits_ref[0] = bits
+def _consts():
+    return (jnp.asarray(dct_kron()),
+            jnp.asarray(freq_weight().reshape(COEFS, 1)))
+
+
+_CONST_SPECS = [pl.BlockSpec((COEFS, COEFS), lambda *_: (0, 0)),
+                pl.BlockSpec((COEFS, 1), lambda *_: (0, 0))]
+
+
+def _chunk_call(kernel, blocks, per_mb, per_mb_spec, extra, extra_specs,
+                interpret):
+    T, _, n = blocks.shape
+    tile = pl.BlockSpec((_SQ, COEFS, TILE), lambda i, t: (t, 0, i))
+    return pl.pallas_call(
+        kernel,
+        grid=(n // TILE, T),
+        in_specs=[tile, per_mb_spec, *extra_specs, *_CONST_SPECS],
+        out_specs=[tile,
+                   pl.BlockSpec((_SQ, 1, TILE), lambda i, t: (t, 0, i))],
+        out_shape=[jax.ShapeDtypeStruct((T, COEFS, n), jnp.float32),
+                   jax.ShapeDtypeStruct((T, 1, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((COEFS, TILE), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(blocks, per_mb, *extra, *_consts())
 
 
 @functools.partial(jax.jit, static_argnames=("clip_refs", "interpret"))
 def mbcodec_chunk_pallas(blocks: jnp.ndarray, qp: jnp.ndarray,
                          clip_refs: bool = False, interpret: bool = False):
-    """Chunk-fused codec: blocks (T, N, 16, 16) f32, qp (T, N) f32 ->
-    (rec (T, N, 16, 16), bits (T, N)). N % TILE == 0 (ops.py pads).
+    """Chunk-fused codec: blocks (T, 256, n) f32, qp (T, 1, n) f32 ->
+    (rec (T, 256, n), bits (T, 1, n)). n % TILE == 0 (ops.py pads).
 
-    Grid (N/TILE, T), T innermost: the decoded reference tile is carried
-    in VMEM scratch across the frame axis, so the whole P-frame chunk
-    scan for a tile runs without leaving VMEM; Pallas double-buffers the
-    (1, TILE, 16, 16) frame-block DMA against the encode of the previous
-    grid step.
+    Grid (n/TILE, T), T innermost: the P-frame reference tile is carried
+    in VMEM scratch across the frame axis, so the whole chunk scan for a
+    tile runs without leaving VMEM.
     """
-    T, n = blocks.shape[:2]
-    d = jnp.asarray(dct_matrix())
-    w = jnp.asarray(freq_weight())
-    return pl.pallas_call(
-        functools.partial(_chunk_kernel, clip_refs),
-        grid=(n // TILE, T),
-        in_specs=[
-            pl.BlockSpec((1, TILE, 16, 16), lambda i, t: (t, i, 0, 0)),
-            pl.BlockSpec((1, TILE), lambda i, t: (t, i)),
-            pl.BlockSpec((16, 16), lambda i, t: (0, 0)),
-            pl.BlockSpec((16, 16), lambda i, t: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, TILE, 16, 16), lambda i, t: (t, i, 0, 0)),
-            pl.BlockSpec((1, TILE), lambda i, t: (t, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((T, n, 16, 16), jnp.float32),
-            jax.ShapeDtypeStruct((T, n), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((TILE, 16, 16), jnp.float32)],
-        interpret=interpret,
-    )(blocks, qp, d, w)
+    return _chunk_call(
+        functools.partial(_chunk_kernel, clip_refs), blocks, qp,
+        pl.BlockSpec((_SQ, 1, TILE), lambda i, t: (t, 0, i)), (), (),
+        interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("clip_refs", "interpret"))
 def mbcodec_chunk_scores_pallas(blocks: jnp.ndarray, pooled: jnp.ndarray,
                                 knobs: jnp.ndarray, clip_refs: bool = False,
                                 interpret: bool = False):
-    """Scores-fused variant: pooled (N,) dilated AccModel scores and
+    """Scores-fused variant: pooled (1, n) dilated AccModel scores and
     knobs (3,) = (alpha, qp_hi, qp_lo) replace the explicit QP array —
     the two-level threshold assignment happens in-register per tile
     (``dilate_scores(s) >= alpha`` == dilate-then-select, see
@@ -178,57 +207,29 @@ def mbcodec_chunk_scores_pallas(blocks: jnp.ndarray, pooled: jnp.ndarray,
     triple is traced: the rate controller moves it per chunk with zero
     recompiles.
     """
-    T, n = blocks.shape[:2]
-    d = jnp.asarray(dct_matrix())
-    w = jnp.asarray(freq_weight())
-    return pl.pallas_call(
-        functools.partial(_chunk_scores_kernel, clip_refs),
-        grid=(n // TILE, T),
-        in_specs=[
-            pl.BlockSpec((1, TILE, 16, 16), lambda i, t: (t, i, 0, 0)),
-            pl.BlockSpec((TILE,), lambda i, t: (i,)),
-            pl.BlockSpec((3,), lambda i, t: (0,)),
-            pl.BlockSpec((16, 16), lambda i, t: (0, 0)),
-            pl.BlockSpec((16, 16), lambda i, t: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, TILE, 16, 16), lambda i, t: (t, i, 0, 0)),
-            pl.BlockSpec((1, TILE), lambda i, t: (t, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((T, n, 16, 16), jnp.float32),
-            jax.ShapeDtypeStruct((T, n), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((TILE, 16, 16), jnp.float32)],
-        interpret=interpret,
-    )(blocks, pooled, knobs, d, w)
+    return _chunk_call(
+        functools.partial(_chunk_scores_kernel, clip_refs), blocks, pooled,
+        pl.BlockSpec((1, TILE), lambda i, t: (0, i)), (knobs,),
+        (pl.BlockSpec(memory_space=pltpu.SMEM),), interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def mbcodec_pallas(blocks: jnp.ndarray, qp: jnp.ndarray,
                    interpret: bool = False):
-    """blocks (N, 16, 16) f32, qp (N,) f32 -> (rec, bits). N % TILE == 0
-    (ops.py pads)."""
-    n = blocks.shape[0]
-    d = jnp.asarray(dct_matrix())
-    w = jnp.asarray(freq_weight())
-    grid = (n // TILE,)
+    """blocks (256, n) f32, qp (1, n) f32 -> (rec (256, n), bits (1, n)).
+    n % TILE == 0 (ops.py pads)."""
+    n = blocks.shape[1]
     return pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TILE, 16, 16), lambda i: (i, 0, 0)),
-            pl.BlockSpec((TILE,), lambda i: (i,)),
-            pl.BlockSpec((16, 16), lambda i: (0, 0)),
-            pl.BlockSpec((16, 16), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((TILE, 16, 16), lambda i: (i, 0, 0)),
-            pl.BlockSpec((TILE,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, 16, 16), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-        ],
+        grid=(n // TILE,),
+        in_specs=[pl.BlockSpec((COEFS, TILE), lambda i: (0, i)),
+                  pl.BlockSpec((1, TILE), lambda i: (0, i)),
+                  *_CONST_SPECS],
+        out_specs=[pl.BlockSpec((COEFS, TILE), lambda i: (0, i)),
+                   pl.BlockSpec((1, TILE), lambda i: (0, i))],
+        out_shape=[jax.ShapeDtypeStruct((COEFS, n), jnp.float32),
+                   jax.ShapeDtypeStruct((1, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(blocks, qp, d, w)
+    )(blocks, qp, *_consts())

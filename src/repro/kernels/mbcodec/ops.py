@@ -65,6 +65,12 @@ def warn_fallback(name: str, substitute: str) -> None:
         f"kernel.", RuntimeWarning, stacklevel=3)
 
 
+def _pad_lanes(x, value):
+    """Pad the last (macroblock) axis up to a TILE multiple."""
+    pad = [(0, 0)] * (x.ndim - 1) + [(0, (-x.shape[-1]) % TILE)]
+    return jnp.pad(x, pad, constant_values=value)
+
+
 def mbcodec(blocks: jnp.ndarray, qp: jnp.ndarray, impl: str = "auto"):
     """blocks (N, 16, 16), qp (N,) -> (rec, bits)."""
     if impl == "auto":
@@ -72,13 +78,10 @@ def mbcodec(blocks: jnp.ndarray, qp: jnp.ndarray, impl: str = "auto"):
     if impl == "ref":
         return mbcodec_ref(blocks, qp)
     n = blocks.shape[0]
-    pad = (-n) % TILE
-    if pad:
-        blocks = jnp.concatenate(
-            [blocks, jnp.zeros((pad, MB, MB), blocks.dtype)])
-        qp = jnp.concatenate([qp, jnp.full((pad,), 30.0, qp.dtype)])
-    rec, bits = mbcodec_pallas(blocks, qp, interpret=(impl == "interpret"))
-    return rec[:n], bits[:n]
+    x = _pad_lanes(blocks.reshape(n, MB * MB).T, 0.0)
+    rec, bits = mbcodec_pallas(x, _pad_lanes(qp.reshape(1, n), 30.0),
+                               interpret=(impl == "interpret"))
+    return rec[:, :n].T.reshape(n, MB, MB), bits[0, :n]
 
 
 def encode_frame_fused(frame: jnp.ndarray, qp_map: jnp.ndarray,
@@ -107,35 +110,32 @@ def encode_frame_fused(frame: jnp.ndarray, qp_map: jnp.ndarray,
 # ---------------------------------------------------------------------------
 # chunk-fused fast-path (registry backends "fused" / "fused_exact")
 # ---------------------------------------------------------------------------
-def _chunk_blocks(frames):
-    """frames (T, H, W, C) -> flat per-channel blocks (T, n_mb*C, 16, 16),
-    padded to a TILE multiple. Returns (blocks, n_real, n_mb, pad)."""
-    T = frames.shape[0]
-    blocks = jax.vmap(blockify)(frames)          # (T, n_mb, C, 16, 16)
-    n_mb = blocks.shape[1]
-    C = blocks.shape[2]
-    blocks = blocks.reshape(T, n_mb * C, MB, MB)
-    n = n_mb * C
-    pad = (-n) % TILE
-    if pad:
-        blocks = jnp.concatenate(
-            [blocks, jnp.zeros((T, pad, MB, MB), blocks.dtype)], axis=1)
-    return blocks, n, n_mb, pad
+def _chunk_lanes(frames):
+    """frames (T, H, W, C) -> lane-major blocks (T, 256, n_mb*C), padded
+    to a TILE multiple: column ``mb * C + c`` is macroblock ``mb``'s
+    channel ``c`` flattened row-major (blockify's order). Returns
+    (blocks, n_real, n_mb)."""
+    T, H, W, C = frames.shape
+    x = frames.reshape(T, H // MB, MB, W // MB, MB, C)
+    x = x.transpose(0, 2, 4, 1, 3, 5).reshape(T, MB * MB, -1)
+    n = x.shape[-1]
+    return _pad_lanes(x, 0.0), n, n // C
 
 
 def _chunk_finish(rec, bits, n, n_mb, H, W, clip_refs):
-    """Kernel outputs (T, n+pad, ...) -> (decoded (T, H, W, C), bytes (T,)).
+    """Kernel outputs (T, 256 | 1, n+pad) -> (decoded (T, H, W, C),
+    bytes (T,)).
 
     Channel bits re-merge to one header per macroblock (codec.block_bits
     charges BLOCK_OVERHEAD once per block, the kernel once per channel
-    tile)."""
+    column)."""
     T = rec.shape[0]
     C = n // n_mb
-    rec = rec[:, :n].reshape(T, n_mb, C, MB, MB)
-    bits_mb = bits[:, :n].reshape(T, n_mb, C).sum(-1) \
+    decoded = rec[:, :, :n].reshape(T, MB, MB, H // MB, W // MB, C)
+    decoded = decoded.transpose(0, 3, 1, 4, 2, 5).reshape(T, H, W, C)
+    bits_mb = bits[:, 0, :n].reshape(T, n_mb, C).sum(-1) \
         - (C - 1) * BLOCK_OVERHEAD
     pbytes = bits_mb.sum(-1) / 8.0
-    decoded = jax.vmap(lambda r: unblockify(r, H, W))(rec)
     if not clip_refs:  # exact path already clipped every reference in-VMEM
         decoded = jnp.clip(decoded, 0.0, 1.0)
     return decoded, pbytes
@@ -197,14 +197,10 @@ def encode_chunk_fused(frames: jnp.ndarray, qp_maps: jnp.ndarray,
             "the clip-corrected XLA scan (fast_exact)" if clip_refs
             else "the shared-map coefficient-space XLA scan (fast family)")
         return _encode_chunk_fused_xla(frames, qp_maps, clip_refs)
-    blocks, n, n_mb, _ = _chunk_blocks(frames)
-    C = n // n_mb
+    blocks, n, n_mb = _chunk_lanes(frames)
     qp = jnp.broadcast_to(qp_maps.reshape(qp_maps.shape[0], -1), (T, n_mb))
-    qp = jnp.repeat(qp, C, axis=1)               # blockify is (mb, C) flat
-    pad = blocks.shape[1] - n
-    if pad:
-        qp = jnp.concatenate(
-            [qp, jnp.full((T, pad), 30.0, qp.dtype)], axis=1)
+    qp = jnp.repeat(qp, n // n_mb, axis=1)       # columns are (mb, C) flat
+    qp = _pad_lanes(qp.reshape(T, 1, n), 30.0)
     rec, bits = mbcodec_chunk_pallas(blocks, qp, clip_refs=clip_refs,
                                      interpret=(impl == "interpret"))
     return _chunk_finish(rec, bits, n, n_mb, H, W, clip_refs)
@@ -234,12 +230,10 @@ def encode_chunk_fused_scores(frames: jnp.ndarray, pooled: jnp.ndarray,
             else "the shared-map coefficient-space XLA scan (fast family)")
         qp_map = jnp.where(pooled >= knobs[0], knobs[1], knobs[2])[None]
         return _encode_chunk_fused_xla(frames, qp_map, clip_refs)
-    blocks, n, n_mb, _ = _chunk_blocks(frames)
-    C = n // n_mb
-    p = jnp.repeat(pooled.reshape(-1), C)
-    pad = blocks.shape[1] - n
-    if pad:  # padded lanes score -inf: always the low-quality level
-        p = jnp.concatenate([p, jnp.full((pad,), -jnp.inf, p.dtype)])
+    blocks, n, n_mb = _chunk_lanes(frames)
+    p = jnp.repeat(pooled.reshape(1, -1), n // n_mb, axis=1)
+    # padded lanes score -inf: always the low-quality level
+    p = _pad_lanes(p.astype(jnp.float32), -jnp.inf)
     rec, bits = mbcodec_chunk_scores_pallas(
         blocks, p, knobs[:3].astype(jnp.float32), clip_refs=clip_refs,
         interpret=(impl == "interpret"))

@@ -105,6 +105,9 @@ def main():
     ap.add_argument("--mesh", default="single")
     ap.add_argument("--top", type=int, default=12)
     args = ap.parse_args()
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
 

@@ -10,10 +10,11 @@ Usage:
   python -m repro.launch.dryrun --arch yi_34b --shape train_4k --mesh single
   python -m repro.launch.dryrun --skip-existing      # resume an interrupted sweep
 """
-# The VERY FIRST lines — before ANY other import — force 512 host devices;
-# jax locks the device count on first backend init.
+# The VERY FIRST lines — before ANY other import — force 512 host devices
+# on the CPU backend; jax locks the device count on first backend init.
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
@@ -147,6 +148,9 @@ def main():
     ap.add_argument("--skip-existing", action="store_true")
     ap.add_argument("--timeout", type=int, default=3600)
     args = ap.parse_args()
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     out_dir = Path(args.out)
 
     cells = list(enumerate_cells(args.arch, args.shape, args.mesh))

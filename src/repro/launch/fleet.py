@@ -1,6 +1,12 @@
 """Multi-process fleet launcher: N worker processes joined over
 ``jax.distributed`` with a CPU coordinator on ``127.0.0.1``.
 
+This is a CPU check of the multi-host control plane, not a chip path:
+the launcher pins ``JAX_PLATFORMS=cpu`` before its first JAX import, so
+the parent (which serves the in-process reference) and every worker it
+starts compute on the CPU, and none of them claims an accelerator that
+one process must own. ``chip_smoke.py`` is the chip path.
+
 The ``tests/_subproc.py`` pattern (fresh interpreters so the JAX backend
 view is per-process), promoted into the package so CI's multihost-smoke
 job, the test suite, and local experiments share one launcher. Each
@@ -33,9 +39,12 @@ per worker under ``DIR/host<k>``.
 """
 from __future__ import annotations
 
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"  # before any JAX import: see above
+
 import argparse
 import json
-import os
 import socket
 import subprocess
 import sys
@@ -62,7 +71,7 @@ def find_free_port(host: str = "127.0.0.1") -> int:
 
 
 def worker_prelude(devices_per_proc: int = 1) -> str:
-    """Python source every worker runs first: CPU platform, optional
+    """Python source every worker runs first: the CPU platform, optional
     host-forced device fan-out, src on sys.path, and the
     ``jax.distributed`` join from the launcher env."""
     force = ""
@@ -72,7 +81,7 @@ def worker_prelude(devices_per_proc: int = 1) -> str:
                  f'{devices_per_proc}"\n        ')
     return textwrap.dedent(f"""
         import os
-        {force}os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        {force}os.environ["JAX_PLATFORMS"] = "cpu"
         import sys
         sys.path.insert(0, {SRC!r})
         import jax, jax.numpy as jnp, numpy as np
@@ -544,6 +553,9 @@ def main(argv: Optional[List[str]] = None) -> None:
                     help="merged Chrome trace path for the elastic "
                          "kill-one-host scenario (smoke)")
     args = ap.parse_args(sys.argv[1:] if argv is None else argv)
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         smoke(trace_out=args.trace_out, metrics_out=args.metrics_out,
               profile=args.profile, kill_trace_out=args.kill_trace_out)

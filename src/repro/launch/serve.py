@@ -37,6 +37,9 @@ def main(argv=None):
     ap.add_argument("--trace-out", default="serve_trace.json",
                     help="Chrome trace output path (with REPRO_OBS=1)")
     args = ap.parse_args(argv)
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     obs.enable_from_env()
 
     from repro.configs.base import get_config, get_reduced_config

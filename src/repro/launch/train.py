@@ -45,6 +45,9 @@ def main(argv=None):
     ap.add_argument("--compression", default=None,
                     choices=[None, "int8", "bf16"])
     args = ap.parse_args(argv)
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.checkpoint.manager import CheckpointManager
     from repro.configs.base import get_config, get_reduced_config

@@ -11,9 +11,8 @@ TensorBoard's profile plugin (or Perfetto's XPlane support).
 
 Multi-process fleets give each worker its own subdirectory
 (``DIR/host<k>``); ``jax.profiler.start_trace`` is per-process.
-Profiling is best-effort: a jaxlib built without profiler support (or a
-second concurrent trace) logs a one-line note instead of failing the
-run — observability must never take the serving path down.
+A requested trace that cannot start or stop raises: a run asked to
+record the device must not exit 0 without the recording.
 """
 from __future__ import annotations
 
@@ -29,7 +28,7 @@ def profile_region(profile_dir: Optional[str],
                    host: Optional[int] = None) -> Iterator[bool]:
     """Run the enclosed block under ``jax.profiler`` tracing into
     ``profile_dir`` (no-op context when ``profile_dir`` is falsy).
-    Yields True when the profiler actually started. Start/stop land as
+    Yields True when the profiler started; raises when it cannot. Start/stop land as
     instants on the span timeline so the profiled window is visible in
     the merged Chrome trace."""
     if not profile_dir:
@@ -40,22 +39,11 @@ def profile_region(profile_dir: Optional[str],
     target = profile_dir if host is None \
         else os.path.join(profile_dir, f"host{host}")
     os.makedirs(target, exist_ok=True)
-    started = False
-    try:
-        jax.profiler.start_trace(target)
-        started = True
-    except Exception as e:  # pragma: no cover - jaxlib-build dependent
-        print(f"[obs] jax.profiler unavailable ({type(e).__name__}: {e}); "
-              f"continuing without a device trace")
+    jax.profiler.start_trace(target)
     _trace.instant("profiler_start", stage="events", dir=target,
-                   active=started)
+                   active=True)
     try:
-        yield started
+        yield True
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception as e:  # pragma: no cover
-                print(f"[obs] jax.profiler.stop_trace failed "
-                      f"({type(e).__name__}: {e})")
+        jax.profiler.stop_trace()
         _trace.instant("profiler_stop", stage="events", dir=target)

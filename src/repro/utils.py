@@ -4,6 +4,8 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import os
+from pathlib import Path
 from typing import Any, Callable, Iterable
 
 import jax
@@ -11,6 +13,26 @@ import jax.numpy as jnp
 import numpy as np
 
 PyTree = Any
+
+#: the compile cache every entry point shares when the environment names
+#: none: a fixed path, because the path is part of the cache key
+REPO_JAX_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here. Otherwise the cache is ``<repo>/.jax_cache``.
+    Entry points (``chip_smoke.py``, ``benchmarks/run.py``,
+    ``repro.launch.*``) call this; tests and package imports do not.
+    Returns the directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(REPO_JAX_CACHE))
+    return str(REPO_JAX_CACHE)
 
 
 def tree_map(f: Callable, *trees: PyTree) -> PyTree:
