@@ -60,6 +60,7 @@ from repro.core.quality import QualityConfig
 from repro.engine.config import EngineConfig
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
+from repro.obs.compile import CompileCounter
 from repro.serve.steps import (make_accuracy_reduce_step,
                                make_camera_fleet_step, make_server_fleet_step,
                                make_tenant_accuracy_reduce_step,
@@ -79,10 +80,20 @@ class _EngineObs:
     pins). ``None`` fields mean that half of the plane is disabled.
 
     All recording uses values the engine already computed for its own
-    accounting — no extra device syncs, no RNG — so telemetry can never
-    perturb the data path (``tests/test_obs.py`` pins bit-identity).
-    Metric recording in the host stage happens *after*
-    ``timing.host_s.append``, so the measured host window stays clean.
+    accounting, or clock reads taken only while the plane is on — no
+    extra device syncs, no RNG — so telemetry can never perturb the data
+    path (``tests/test_obs.py`` pins bit-identity). Metric recording in
+    the host stage happens *after* ``timing.host_s.append``, so the
+    measured host window stays clean.
+
+    The loop's spans, one of each per chunk interval ``ci``, mark its
+    layer boundaries on the host: ``ingest`` (host slice + device put),
+    ``dispatch_camera``, ``wait_camera`` (the host blocked on the camera
+    step), ``dispatch_server`` (server, reference and accuracy steps),
+    ``fetch`` (device-to-host copy of the interval's outputs or accuracy
+    vector) and ``scoring`` (exactly ``FleetTiming.host_s``, which holds
+    the wire bytes' copy). Each ``span`` call reads
+    the clock for the end of the span.
     """
 
     __slots__ = ("tracer", "reg", "cam_c", "srv_c", "host_c",
@@ -101,26 +112,35 @@ class _EngineObs:
             self.delay_h = reg.histogram("chunk_delay_s")
             self.queue_h = reg.histogram("uplink_queue_s")
 
-    def camera(self, ci: int, t0: float, wall: float, acct: float,
+    def span(self, name: str, lane: str, t0: float, **args) -> None:
+        """A span from ``t0`` to now on ``lane``."""
+        if self.tracer is not None:
+            self.tracer.complete(name, lane, t0, time.perf_counter() - t0,
+                                 **args)
+
+    def camera(self, ci: int, t0: float, t_wait: float, acct: float,
                n_lanes: int, n_active: int) -> None:
-        tr = self.tracer
-        if tr is not None:
-            tr.complete("camera", "camera", t0, wall, ci=ci,
-                        lanes=n_lanes, active=n_active)
+        """The camera step is ready: ``wait_camera`` spans the host's
+        block from ``t_wait``; the counters take the accounting charge
+        and the dispatch-to-ready wall time from ``t0``."""
+        t1 = time.perf_counter()
+        if self.tracer is not None:
+            self.tracer.complete("wait_camera", "camera", t_wait,
+                                 t1 - t_wait, ci=ci)
         if self.reg is not None:
-            self.cam_c.inc(acct if acct is not None else wall)
-            self.stage_h["camera"].observe(wall)
+            self.cam_c.inc(acct)
+            self.stage_h["camera"].observe(t1 - t0)
             self.reg.gauge("lanes_active").set(n_active)
             self.reg.gauge("lanes_padded").set(n_lanes - n_active)
 
-    def server(self, ci: int, t0: float, dur: float,
-               estimated: bool) -> None:
-        tr = self.tracer
-        if tr is not None:
-            args = {"ci": ci}
-            if estimated:  # overlapped: steady-state estimate, the same
-                args["estimated"] = True  # number FleetTiming reports
-            tr.complete("server", "server", t0, dur, **args)
+    def server(self, dur: float, ci: Optional[int] = None,
+               t0: Optional[float] = None) -> None:
+        """Server-step seconds for the counters, and a ``server`` span
+        where ``t0`` is given: serialized mode measures the step, while
+        overlap mode charges the warm-up estimate, which has no place on
+        the timeline."""
+        if self.tracer is not None and t0 is not None:
+            self.tracer.complete("server", "server", t0, dur, ci=ci)
         if self.reg is not None:
             self.srv_c.inc(dur)
             self.stage_h["server"].observe(dur)
@@ -131,18 +151,9 @@ class _EngineObs:
         Called after ``timing.host_s.append`` so none of this work lands
         inside the measured host window."""
         tr = self.tracer
-        tail = max(delays[:n_active], default=0.0) if n_active else 0.0
         if tr is not None:
             tr.complete("scoring", "scoring", t0, host_dur, ci=ci,
                         active=n_active)
-            if n_active:
-                # modelled transmit time (the accounting clock, not wall
-                # clock): anchored at the scoring instant, duration =
-                # the batch-tail upload + backlog wait
-                tr.complete("uplink", "uplink", t0, queue_s + tail,
-                            ci=ci, queue_s=queue_s,
-                            bytes=float(sum(lane_bytes[:n_active])),
-                            modelled=True)
         if self.reg is not None:
             self.host_c.inc(host_dur)
             self.stage_h["host"].observe(host_dur)
@@ -563,16 +574,24 @@ class MultiStreamEngine:
         return jax.device_put(x, sharding) if sharding is not None else x
 
     def _steady_times(self, camera, server_step, warm, refs_none: bool,
-                      overlap: bool, key, acc_step=None):
+                      overlap: bool, key, acc_step=None, programs=None):
         """Compile the camera + server programs for this batch shape
         outside the timed loop, then (overlap mode) time one hot step of
         each — the steady-state estimates per-stream ``encode_s`` and
         ``timing.server_s`` report while the pipelined loop's
         dispatch->ready spans absorb overlapped work. Cached per
         (shape, mesh, refs mode, ...) so repeat visits to a fleet shape
-        skip the warm-up device work entirely."""
+        skip the warm-up device work entirely.
+
+        With the tracer on, one ``warm`` span covers all of it; its
+        ``compiled`` arg names the ``programs`` (name -> jitted callable)
+        whose jit cache grew, so a compile inside a measured window shows
+        on the timeline."""
         if key in self._warm:
             return self._warm[key]
+        ob = self._obs
+        if ob is not None and ob.tracer is not None:
+            counter = CompileCounter(**(programs or {}))
         t_warm = time.perf_counter()
         d0, _, _ = camera(warm)
         jax.block_until_ready(d0)
@@ -580,13 +599,6 @@ class MultiStreamEngine:
         jax.block_until_ready(jax.tree_util.tree_leaves(so))
         if acc_step is not None:  # compile the device accuracy reduce too
             jax.block_until_ready(acc_step(so, so))
-        tracer = obs_trace.get_tracer()
-        if tracer is not None:  # compiles stall a host mid-run: make the
-            # warm-up visible on the timeline instead of vanishing into
-            # the gap between intervals
-            tracer.complete("warm_compile", "warmup", t_warm,
-                            time.perf_counter() - t_warm,
-                            shape=list(warm.shape))
         reg = obs_metrics.get_metrics()
         if reg is not None:
             reg.counter("warm_compiles_total").inc()
@@ -604,6 +616,10 @@ class MultiStreamEngine:
                 jax.block_until_ready(
                     jax.tree_util.tree_leaves(server_step(warm)))
             server_steady_s = time.perf_counter() - t0
+        if ob is not None and ob.tracer is not None:
+            ob.span("warm", "warmup", t_warm, shape=list(warm.shape),
+                    compiled=sorted(n for n, d in counter.growth().items()
+                                    if d > 0))
         self._warm[key] = (cam_steady_s, server_steady_s)
         return self._warm[key]
 
@@ -679,7 +695,12 @@ class MultiStreamEngine:
         appends nothing. When the chunk carries a device-reduced accuracy
         vector (``p["acc_dev"]``) the dense output trees were never
         fetched at all."""
+        ob = self._obs
+        if ob is not None:
+            t_fetch = time.perf_counter()
+        ci = p["ci"]
         acc_dev = p.get("acc_dev")
+        outs = ref_outs = None
         if acc_dev is None:
             # bulk-fetch device results to host once, then keep the
             # scoring in numpy — per-stream device slicing would enqueue
@@ -693,10 +714,14 @@ class MultiStreamEngine:
             # beside the bulk fetch above: blocking on the device here
             # would charge server compute to the host_s accounting
             acc_dev = np.asarray(acc_dev)
+        if ob is not None:  # the wire bytes' copy stays in host_s
+            fetched = [acc_dev] if outs is None else \
+                list(outs.values()) + list((ref_outs or {}).values())
+            ob.span("fetch", "fetch", t_fetch, ci=ci,
+                    bytes=int(sum(a.nbytes for a in fetched)))
         if overlap:
             timing.server_s.append(p["server_steady_s"])
         t0 = time.perf_counter()
-        ci = p["ci"]
         ids = p.get("ids")  # serve_loop: active lane i -> stream ids[i]
         pbytes = np.asarray(p["pbytes"])
         n_lanes = pbytes.shape[0]
@@ -799,10 +824,9 @@ class MultiStreamEngine:
                 used_knobs=p.get("knobs"))
         host_dur = time.perf_counter() - t0
         timing.host_s.append(host_dur)
-        ob = self._obs
         if ob is not None:  # after host_s.append: outside the host window
             if overlap:
-                ob.server(ci, t0, p["server_steady_s"], True)
+                ob.server(p["server_steady_s"])
             ob.finish(ci, t0, host_dur, n_active, lane_bytes, delays,
                       queue_s, p["cam_dt"])
 
@@ -831,8 +855,13 @@ class MultiStreamEngine:
             self.controller.reset()
         clock = None if self.trace is None else \
             UplinkClock(self.trace, cs, self.fps)
-        self._obs = _EngineObs() \
+        self._obs = ob = _EngineObs() \
             if (obs_trace.enabled() or obs_metrics.enabled()) else None
+        if ob is not None:
+            t_call = time.perf_counter()
+        programs = {"camera": cam_step, "server": server_step}
+        if use_dev:
+            programs["acc"] = acc_step
         tids_dev = None
         if self._tenanted:
             # the per-lane tenant-id lane: stream i IS lane i in run(),
@@ -865,7 +894,8 @@ class MultiStreamEngine:
         else:
             cam_steady_s, server_steady_s = self._steady_times(
                 camera, server_step, put(frames[:, : cs]), refs is None,
-                self.overlap, warm_key, acc_step=acc_step)
+                self.overlap, warm_key, acc_step=acc_step,
+                programs=programs)
 
         # ``depth`` chunks stay in flight (2 = the classic double buffer):
         # at iteration ci the host scores chunk ci-depth, whose server
@@ -877,13 +907,21 @@ class MultiStreamEngine:
         depth = self.depth
         t_run = time.perf_counter()
         for ci, s in enumerate(starts):
+            if ob is not None:
+                t_in = time.perf_counter()
             batch = put(frames[:, s : s + cs])
+            if ob is not None:
+                ob.span("ingest", "ingest", t_in, ci=ci, bytes=batch.nbytes)
             knobs_used = self.controller.knobs() if controlled else None
             t0 = time.perf_counter()
             decoded, pbytes, _ = camera(batch)    # async dispatch
+            if ob is not None:
+                ob.span("dispatch_camera", "dispatch", t0, ci=ci)
             if self.overlap and len(pending) >= depth:
                 self._finish(pending.pop(0), per_stream, net, refs,
                              timing, True, clock)
+            if ob is not None:
+                t_wait = time.perf_counter()
             jax.block_until_ready(decoded)
             cam_dt = cam_steady_s if self.overlap \
                 else time.perf_counter() - t0
@@ -892,10 +930,8 @@ class MultiStreamEngine:
             # simulation constant (deterministic delay replay / parity)
             acct_dt = cam_dt if self.sim_encode_s is None \
                 else self.sim_encode_s
-            if self._obs is not None:
-                wall = cam_dt if not self.overlap \
-                    else time.perf_counter() - t0
-                self._obs.camera(ci, t0, wall, cam_dt, N, N)
+            if ob is not None:
+                ob.camera(ci, t0, t_wait, cam_dt, N, N)
             t1 = time.perf_counter()
             outs = server_step(decoded)           # batched server DNN
             ref_outs = server_step(batch) if refs is None else None
@@ -909,6 +945,8 @@ class MultiStreamEngine:
             else:
                 acc_dev = None
                 entry = dict(ci=ci, outs=outs, ref_outs=ref_outs)
+            if ob is not None:
+                ob.span("dispatch_server", "dispatch", t1, ci=ci)
             entry.update(pbytes=pbytes, cam_dt=acct_dt,
                          server_steady_s=server_steady_s,
                          knobs=knobs_used)
@@ -923,14 +961,17 @@ class MultiStreamEngine:
                             jax.tree_util.tree_leaves(ref_outs))
                 srv_dt = time.perf_counter() - t1
                 timing.server_s.append(srv_dt)
-                if self._obs is not None:
-                    self._obs.server(ci, t1, srv_dt, False)
+                if ob is not None:
+                    ob.server(srv_dt, ci, t1)
                 self._finish(pending.pop(0), per_stream, net, refs,
                              timing, False, clock)
         while pending:
             self._finish(pending.pop(0), per_stream, net, refs, timing,
                          self.overlap, clock)
         timing.wall_s = time.perf_counter() - t_run
+        if ob is not None:
+            ob.span("run", "events", t_call, intervals=len(starts),
+                    streams=N)
         if self.autoscaler is not None:
             width = mesh.devices.size if mesh is not None else 1
             # tenant_streams only rides when tenanted: autoscaler
@@ -945,9 +986,9 @@ class MultiStreamEngine:
             if self._tenanted else None
         if windowed:
             agg, self._agg = self._agg.result(), None
-            if self._obs is not None:
-                self._obs.slo_attainment(agg, self.tenants
-                                         if self._tenanted else None)
+            if ob is not None:
+                ob.slo_attainment(agg, self.tenants
+                                  if self._tenanted else None)
             return FleetResult([], timing.camera_s, timing=timing,
                                aggregate=agg, served_cis=served_cis)
         streams = [RunResult(f"accmpeg_fleet[{i}]", per_stream[i])
@@ -1098,8 +1139,10 @@ class MultiStreamEngine:
         served_cis: List[int] = []
         last_dec = None  # (device decoded batch, n_active) of the last
         # served interval — exported as the resume state's warm reference
-        self._obs = _EngineObs() \
+        self._obs = ob = _EngineObs() \
             if (obs_trace.enabled() or obs_metrics.enabled()) else None
+        if ob is not None:
+            t_call = time.perf_counter()
         decisions: List = []
         pending: List[dict] = []
         warm_s = 0.0  # per-shape compiles land mid-loop under churn;
@@ -1108,10 +1151,10 @@ class MultiStreamEngine:
         for ci in range(start_chunk, stop):
             s = starts[ci]
             active_ids = apply_churn(active_ids, events, ci)
-            if self._obs is not None:
+            if ob is not None:
                 for ev in events:
                     if ev.chunk == ci and (ev.join or ev.leave):
-                        self._obs.churn(ci, ev)
+                        ob.churn(ci, ev)
             if owned_set is not None:
                 stray = sorted(sid for sid in active_ids
                                if sid not in owned_set)
@@ -1135,12 +1178,10 @@ class MultiStreamEngine:
             depth = self.depth if self.overlap else 1
             cam_step, server_step, mesh = self._steps_for(plan.n_padded,
                                                           masked=True)
+            programs = {"camera": cam_step, "server": server_step}
             sharding = stream_sharding(mesh) if mesh is not None else None
             mask_dev = self._put(plan.active, sharding)
             ids = list(active_ids)
-            # advanced index + slice in one step: copies one chunk's
-            # worth of frames, not each active stream's whole timeline
-            batch_np = pad_streams(frames[ids, s : s + cs], plan.n_padded)
             tids_dev = None
             t_counts = None
             if self._tenanted:
@@ -1153,8 +1194,8 @@ class MultiStreamEngine:
                 server_step = (lambda d, _s=server_step, _t=tids_dev:
                                _s(d, _t))
                 t_counts = self._tenant_counts(ids)
-                if self._obs is not None:
-                    self._obs.tenant_lanes(self.tenants, t_counts)
+                if ob is not None:
+                    ob.tenant_lanes(self.tenants, t_counts)
 
             def camera(batch, _cam=cam_step, _mask=mask_dev,
                        _tids=tids_dev):
@@ -1166,9 +1207,16 @@ class MultiStreamEngine:
                 return _cam(batch, _mask)
 
             acc_step = self._acc_step_for(mesh) if use_dev else None
+            if use_dev:
+                programs["acc"] = acc_step
             if use_dev and self._tenanted:
                 acc_step = (lambda o, r, _a=acc_step, _t=tids_dev:
                             _a(o, r, _t))
+            if ob is not None:
+                t_in = time.perf_counter()
+            # advanced index + slice in one step: copies one chunk's
+            # worth of frames, not each active stream's whole timeline
+            batch_np = pad_streams(frames[ids, s : s + cs], plan.n_padded)
             warm_key = (batch_np.shape, mesh, refs is None, self.overlap,
                         controlled, use_dev, "masked")
             if warm_key in self._warm:  # hot shape: skip the warm put
@@ -1178,18 +1226,26 @@ class MultiStreamEngine:
                 cam_steady_s, server_steady_s = self._steady_times(
                     camera, server_step, self._put(batch_np, sharding),
                     refs is None, self.overlap, warm_key,
-                    acc_step=acc_step)
-                warm_s += time.perf_counter() - t_warm
+                    acc_step=acc_step, programs=programs)
+                # a cold shape's ingest span covers the put alone
+                t_in = time.perf_counter()
+                warm_s += t_in - t_warm
 
             host_before = len(timing.host_s)
             t_int = time.perf_counter()
             batch = self._put(batch_np, sharding)
+            if ob is not None:
+                ob.span("ingest", "ingest", t_in, ci=ci, bytes=batch.nbytes)
             knobs_used = self.controller.knobs() if controlled else None
             t0 = time.perf_counter()
             decoded, pbytes, _ = camera(batch)    # async dispatch
+            if ob is not None:
+                ob.span("dispatch_camera", "dispatch", t0, ci=ci)
             if self.overlap and len(pending) >= depth:
                 self._finish(pending.pop(0), per_stream, net, refs,
                              timing, True, clock)
+            if ob is not None:
+                t_wait = time.perf_counter()
             jax.block_until_ready(decoded)
             cam_dt = cam_steady_s if self.overlap \
                 else time.perf_counter() - t0
@@ -1198,11 +1254,8 @@ class MultiStreamEngine:
             last_dec = (decoded, len(ids))
             acct_dt = cam_dt if self.sim_encode_s is None \
                 else self.sim_encode_s
-            if self._obs is not None:
-                wall = cam_dt if not self.overlap \
-                    else time.perf_counter() - t0
-                self._obs.camera(ci, t0, wall, cam_dt, plan.n_padded,
-                                 len(ids))
+            if ob is not None:
+                ob.camera(ci, t0, t_wait, cam_dt, plan.n_padded, len(ids))
             t1 = time.perf_counter()
             outs = server_step(decoded)           # batched server DNN
             ref_outs = server_step(batch) if refs is None else None
@@ -1214,6 +1267,8 @@ class MultiStreamEngine:
                 acc_dev = None
                 entry = dict(ci=ci, ids=ids, outs=outs,
                              ref_outs=ref_outs)
+            if ob is not None:
+                ob.span("dispatch_server", "dispatch", t1, ci=ci)
             entry.update(pbytes=pbytes, cam_dt=acct_dt,
                          server_steady_s=server_steady_s,
                          knobs=knobs_used)
@@ -1228,8 +1283,8 @@ class MultiStreamEngine:
                             jax.tree_util.tree_leaves(ref_outs))
                 srv_dt = time.perf_counter() - t1
                 timing.server_s.append(srv_dt)
-                if self._obs is not None:
-                    self._obs.server(ci, t1, srv_dt, False)
+                if ob is not None:
+                    ob.server(srv_dt, ci, t1)
                 self._finish(pending.pop(0), per_stream, net, refs,
                              timing, False, clock)
             if rescale and (ci + 1) % max(decide_every, 1) == 0:
@@ -1262,6 +1317,9 @@ class MultiStreamEngine:
             self._finish(pending.pop(0), per_stream, net, refs, timing,
                          self.overlap, clock)
         timing.wall_s = time.perf_counter() - t_run - warm_s
+        if ob is not None:
+            ob.span("run", "events", t_call, intervals=len(served_cis),
+                    streams=N_total)
         # export the resume state (see the docstring): whatever a
         # draining host must carry for its adopter to continue this run
         # bit-exactly from ``stop``
@@ -1282,9 +1340,9 @@ class MultiStreamEngine:
         }
         if windowed:
             agg, self._agg = self._agg.result(), None
-            if self._obs is not None:
-                self._obs.slo_attainment(agg, self.tenants
-                                         if self._tenanted else None)
+            if ob is not None:
+                ob.slo_attainment(agg, self.tenants
+                                  if self._tenanted else None)
             return FleetResult([], timing.camera_s, timing=timing,
                                stream_ids=list(agg.stream_ids),
                                decisions=decisions,

@@ -28,10 +28,10 @@ Design constraints, in order:
    through the existing ``KVExchange`` allgather.
 
 Timeline layout: ``pid`` = host id, ``tid`` = stage lane. The stage
-vocabulary (:data:`STAGES`) covers the serving pipeline — camera step,
-server step, uplink transmit, host scoring, admission, controller,
-warm-up/compile — and instants land on the lane of the stage that
-caused them.
+vocabulary (:data:`STAGES`) covers the serving pipeline — chunk ingest,
+step dispatch, the host's wait on the camera step, the server step,
+result fetch, host scoring, admission, controller, warm-up/compile —
+and instants land on the lane of the stage that caused them.
 """
 from __future__ import annotations
 
@@ -43,8 +43,8 @@ from typing import Dict, List, Optional, Sequence
 
 #: pipeline-stage lanes, in display order (Chrome sorts by the
 #: thread_sort_index metadata emitted alongside the spans)
-STAGES = ("camera", "server", "uplink", "scoring", "admission",
-          "controller", "autoscaler", "warmup", "events")
+STAGES = ("ingest", "dispatch", "camera", "server", "fetch", "scoring",
+          "admission", "controller", "autoscaler", "warmup", "events")
 
 
 @dataclasses.dataclass

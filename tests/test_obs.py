@@ -329,8 +329,9 @@ def _digest(res):
 def test_serve_loop_bit_identical_with_plane_on(engine, fleet):
     """The acceptance criterion: telemetry on vs off, same schedule
     (with churn), bit-identical data path — and the plane saw every
-    interval: camera spans match ``FleetTiming`` entry-for-entry, stage
-    counters reconcile with the timing sums, churn left an instant."""
+    interval: one ``wait_camera`` span on the camera lane per
+    ``FleetTiming`` entry, stage counters reconcile with the timing
+    sums, churn left an instant."""
     from repro.control import ChurnEvent
 
     events = [ChurnEvent(1, leave=(2,)), ChurnEvent(2, join=(2,))]
@@ -342,8 +343,13 @@ def test_serve_loop_bit_identical_with_plane_on(engine, fleet):
 
     cam_spans = tr.stage_events("camera")
     assert len(cam_spans) == len(res_on.timing.camera_s) == 3
+    assert {e.name for e in cam_spans} == {"wait_camera"}
     assert [e.args["ci"] for e in cam_spans] == [0, 1, 2]
-    # span durations are real wall occupancy (in overlap mode the
+    # overlap mode charges the warm-up estimate to the server stage: it
+    # reaches the counters, never the timeline
+    assert tr.stage_events("server") == []
+    assert tr.stage_events("uplink") == []
+    # span durations are the host's real wait (in overlap mode the
     # FleetTiming entry is the steady-state accounting value instead);
     # exactness is pinned via the counters below, which carry the same
     # accounting values FleetTiming does
@@ -357,7 +363,7 @@ def test_serve_loop_bit_identical_with_plane_on(engine, fleet):
     assert len(churn) == 2
     assert reg.get("churn_leaves_total").value == 1
     assert reg.get("churn_joins_total").value == 1
-    # per-chunk uplink/scoring spans + admission counters also landed
+    # per-chunk scoring spans + admission counters also landed
     assert len(tr.stage_events("scoring")) == 3
     assert reg.get("admissions_total").value == 3
     assert reg.get("chunks_served_total").value == 3 + 2 + 3
@@ -365,6 +371,94 @@ def test_serve_loop_bit_identical_with_plane_on(engine, fleet):
     trace = tr.chrome_trace()
     assert {e["pid"] for e in trace["traceEvents"]} == {0}
     assert reg.to_prometheus() and reg.to_jsonl()
+
+
+#: the engine loop's spans, one of each per chunk interval, and their lanes
+INTERVAL_SPANS = {"ingest": "ingest", "dispatch_camera": "dispatch",
+                  "wait_camera": "camera", "dispatch_server": "dispatch",
+                  "fetch": "fetch", "scoring": "scoring"}
+
+
+def _serve(engine, fleet, entry):
+    if entry == "run":
+        return engine.run(fleet)
+    return engine.serve_loop(fleet)
+
+
+@pytest.mark.parametrize("entry", ["run", "serve_loop"])
+def test_engine_spans_one_per_interval(engine, fleet, entry):
+    """Every layer boundary of the loop leaves one span per interval,
+    each carrying the interval id, in the order the host crosses them;
+    one ``run`` span covers the call."""
+    _serve(engine, fleet, entry)  # warm: the spans below are steady state
+    tr = obs.trace.install()
+    res = _serve(engine, fleet, entry)
+    obs.disable()
+    n = len(res.timing.camera_s)
+    assert n == 3
+    by_name = {}
+    for e in tr.events:
+        by_name.setdefault(e.name, []).append(e)
+    assert set(by_name) == set(INTERVAL_SPANS) | {"run"}
+    for name, lane in INTERVAL_SPANS.items():
+        evs = by_name[name]
+        assert [e.args["ci"] for e in evs] == list(range(n)), name
+        assert {e.stage for e in evs} == {lane}
+        assert all(e.dur >= 0 for e in evs)
+    for ci in range(n):
+        first = {name: by_name[name][ci].ts for name in INTERVAL_SPANS}
+        assert first["ingest"] <= first["dispatch_camera"] \
+            <= first["wait_camera"] <= first["dispatch_server"]
+    # serve_loop pads its 3 streams to 4 lanes (a power of two)
+    lanes = 3 if entry == "run" else 4
+    lane_bytes = fleet[0, :CS].astype(np.float32).nbytes
+    assert all(e.args["bytes"] == lanes * lane_bytes
+               for e in by_name["ingest"])
+    assert all(e.args["bytes"] > 0 for e in by_name["fetch"])
+    # scoring is exactly the FleetTiming.host_s window
+    assert [e.dur for e in by_name["scoring"]] == res.timing.host_s
+    (call,) = by_name["run"]
+    assert call.args == {"intervals": n, "streams": fleet.shape[0]}
+    assert all(call.ts <= e.ts and e.ts + e.dur <= call.ts + call.dur
+               for e in tr.events if e is not call)
+
+
+def test_warm_span_names_what_compiled(engine, fleet):
+    """``warm`` covers the whole in-call warm-up and names the programs
+    whose jit cache grew: both steps for a new fleet shape, none for a
+    new clip length at a known chunk shape (a warm-up inside the call
+    that compiles nothing), and no span once the shape is cached."""
+    tr = obs.trace.install()
+    engine.run(fleet[:2])          # two streams: a new batch shape
+    engine.run(fleet[:2, :2 * CS])  # known chunk shape, new clip length
+    engine.run(fleet[:2, :2 * CS])  # cached: no warm-up at all
+    obs.disable()
+    warm = tr.stage_events("warmup")
+    assert [e.name for e in warm] == ["warm", "warm"]
+    assert warm[0].args["compiled"] == ["camera", "server"]
+    assert warm[1].args["compiled"] == []
+    assert warm[0].args["shape"] == [2, CS, H, W, 3]
+    runs = [e for e in tr.events if e.name == "run"]
+    assert len(runs) == 3
+    # the warm-up runs inside its call's span
+    for w, r in zip(warm, runs):
+        assert r.ts <= w.ts and w.ts + w.dur <= r.ts + r.dur
+
+
+@pytest.mark.parametrize("entry", ["run", "serve_loop"])
+def test_plane_off_records_nothing(engine, fleet, monkeypatch, entry):
+    """With the plane off the loop never builds its telemetry handles,
+    so no span method runs and a tracer left uninstalled stays empty."""
+    from repro.engine import multistream
+
+    def refuse():
+        raise AssertionError("telemetry handles built with the plane off")
+
+    monkeypatch.setattr(multistream, "_EngineObs", refuse)
+    tr = Tracer()
+    res = _serve(engine, fleet, entry)
+    assert len(res.timing.camera_s) == 3
+    assert engine._obs is None and tr.events == []
 
 
 def test_controller_records_level_transitions():
