@@ -295,30 +295,49 @@ def keypoint_accuracy(out, ref_out, radius=2.0):
 # aggregation parity tests pin.
 
 def _decode_detection_frames(keep_np, wh, thresh=0.3, topk=50):
-    """Decode a flat (F, hs, ws) stack of suppressed heatmaps into F
-    per-frame detection lists. One global ``np.where`` + searchsorted
-    frame grouping replaces F per-frame ``np.where`` calls; row-major
-    ordering makes each frame's candidate order — and therefore its
-    argsort tiebreaks and final boxes — identical to
-    :func:`decode_detections` on that frame alone."""
+    """Decode a flat (F, hs, ws) stack of suppressed heatmaps into padded
+    box arrays: ``(boxes, counts)``, boxes (F, K, 4) float64 corners
+    (x0, y0, x1, y1) in descending score order, counts (F,) the number of
+    real boxes per frame, K = max(counts, 1) and the padding zeros.
+
+    One global ``np.where`` + searchsorted frame grouping replaces F
+    per-frame ``np.where`` calls; row-major ordering gives each frame the
+    candidate order, and the same per-frame ``np.argsort`` the same
+    tiebreaks, as :func:`decode_detections` on that frame alone. The
+    corners follow its dtype path too (half extents in ``wh``'s dtype,
+    centres and corners in float64), so every coordinate is bit-equal."""
+    n_frames = keep_np.shape[0]
     fs, ys_all, xs_all = np.where(keep_np >= thresh)
-    bounds = np.searchsorted(fs, np.arange(keep_np.shape[0] + 1))
-    results = []
-    for b in range(keep_np.shape[0]):
-        lo, hi = bounds[b], bounds[b + 1]
-        ys, xs = ys_all[lo:hi], xs_all[lo:hi]
-        scores = keep_np[b][ys, xs]
-        order = np.argsort(-scores)[:topk]
-        dets = []
-        for i in order:
-            y, x = ys[i], xs[i]
-            w, h = np.maximum(wh[b, y, x], 0.5)
-            cx, cy = (x + 0.5) * STRIDE, (y + 0.5) * STRIDE
-            dets.append((cx - w * STRIDE / 2, cy - h * STRIDE / 2,
-                         cx + w * STRIDE / 2, cy + h * STRIDE / 2,
-                         float(scores[i])))
-        results.append(dets)
-    return results
+    bounds = np.searchsorted(fs, np.arange(n_frames + 1))
+    scores = keep_np[fs, ys_all, xs_all]
+    picks = np.concatenate([lo + np.argsort(-scores[lo:hi])[:topk]
+                            for lo, hi in zip(bounds[:-1], bounds[1:])])
+    counts = np.minimum(np.diff(bounds), topk)
+    frame = np.repeat(np.arange(n_frames), counts)
+    rank = np.arange(picks.size) - np.repeat(np.cumsum(counts) - counts,
+                                             counts)
+    ys, xs = ys_all[picks], xs_all[picks]
+    half = np.maximum(wh[frame, ys, xs], 0.5) * STRIDE / 2   # (M, 2)
+    cx, cy = (xs + 0.5) * STRIDE, (ys + 0.5) * STRIDE
+    corners = np.stack([cx - half[:, 0], cy - half[:, 1],
+                        cx + half[:, 0], cy + half[:, 1]], axis=-1)
+    boxes = np.zeros((n_frames, max(int(counts.max()), 1), 4), np.float64)
+    boxes[frame, rank] = corners
+    return boxes, counts
+
+
+def _iou_frames(a, b):
+    """(F, Ka, Kb) IoU of every box pair within each frame for padded
+    (F, K, 4) box arrays: :func:`_iou`'s operations in its order."""
+    a, b = a[:, :, None, :], b[:, None, :, :]
+    ix0, iy0 = np.maximum(a[..., 0], b[..., 0]), np.maximum(a[..., 1],
+                                                             b[..., 1])
+    ix1, iy1 = np.minimum(a[..., 2], b[..., 2]), np.minimum(a[..., 3],
+                                                             b[..., 3])
+    inter = np.maximum(0.0, ix1 - ix0) * np.maximum(0.0, iy1 - iy0)
+    ua = ((a[..., 2] - a[..., 0]) * (a[..., 3] - a[..., 1])
+          + (b[..., 2] - b[..., 0]) * (b[..., 3] - b[..., 1]) - inter)
+    return np.divide(inter, ua, out=np.zeros_like(inter), where=ua > 0)
 
 
 def _lane_keep(out):
@@ -338,20 +357,45 @@ def _lane_keep(out):
 def detection_f1_batched(out, ref_out, iou_thresh=0.5):
     """Per-lane mean-F1 for lane trees with leaves (N, T, ...); returns
     (N,) float64, each entry bit-equal to ``detection_f1`` on that lane's
-    slice."""
-    keep = _lane_keep(out)
+    slice.
+
+    One vectorized numpy pass over all N*T frames, with no Python work per
+    box or box pair: padded decode, one (F, K, K) IoU tensor, and the
+    greedy match stepped over detection rank (at most ``topk`` steps of
+    (F, K) arrays). Detections come out of the decode in descending score
+    order, which is ``detection_f1``'s greedy order; at each rank the
+    first-index argmax over the still-unmatched references is its
+    ``i > best`` scan from ``best = 0.0``. It stays on the host: the
+    references are often precomputed D(H) held there, and a float32
+    device IoU would not be bit-equal to this float64 one."""
     wh = np.asarray(out["wh"])
     n, t = wh.shape[:2]
-    wh = wh.reshape((n * t,) + wh.shape[2:])
-    ref_keep = _lane_keep(ref_out)
+    if n * t == 0:
+        return np.ones(n, np.float64)
     ref_wh = np.asarray(ref_out["wh"])
-    ref_wh = ref_wh.reshape((n * t,) + ref_wh.shape[2:])
-    dets = _decode_detection_frames(keep, wh)
-    refs = _decode_detection_frames(ref_keep, ref_wh)
-    return np.asarray([
-        detection_f1(dets[b * t:(b + 1) * t], refs[b * t:(b + 1) * t],
-                     iou_thresh)
-        for b in range(n)], np.float64)
+    dets, n_d = _decode_detection_frames(
+        _lane_keep(out), wh.reshape((n * t,) + wh.shape[2:]))
+    refs, n_r = _decode_detection_frames(
+        _lane_keep(ref_out), ref_wh.reshape((n * t,) + ref_wh.shape[2:]))
+    iou = _iou_frames(dets, refs)                     # (F, Kd, Kr)
+    frames = np.arange(n * t)
+    matched = np.arange(refs.shape[1]) >= n_r[:, None]   # padding: taken
+    tp = np.zeros(n * t, np.int64)
+    for k in range(int(n_d.max())):
+        row = np.where(matched, -1.0, iou[:, k])
+        j = row.argmax(axis=1)
+        top = row[frames, j]
+        # detection_f1's best starts at 0.0 and moves on a strictly
+        # larger IoU, so it ends at max(0, top), matching ref j iff top > 0
+        hit = (k < n_d) & (np.maximum(top, 0.0) >= iou_thresh)
+        take = hit & (top > 0)
+        matched[frames[take], j[take]] = True
+        tp += hit
+    prec = tp / np.maximum(n_d, 1)
+    rec = tp / np.maximum(n_r, 1)
+    f1 = np.where((n_d == 0) & (n_r == 0), 1.0,
+                  2 * prec * rec / np.maximum(prec + rec, 1e-9))
+    return f1.reshape(n, t).mean(axis=1)
 
 
 def segmentation_iou_batched(out, ref_out):

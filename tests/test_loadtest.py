@@ -6,7 +6,8 @@ cross-host wire format.
    ``detail="legacy"`` per-lane loop's totals on a churny generated
    schedule: byte sums bit-equal, accuracy sums to summation order, p90
    exact; ``detail="chunks"`` (vectorized, full lists) is bit-identical
-   to legacy chunk for chunk.
+   to legacy chunk for chunk, on a segmentation fleet and on a
+   detection fleet (vectorized host F1).
 2. Regression — a drained pending chunk with an empty active set
    (``ids=()``) must not feed the controller a max() over nothing; the
    old per-lane path raised ValueError there.
@@ -105,6 +106,44 @@ def test_windowed_matches_legacy_on_churned_schedule(models, workload,
     assert res_w.n_streams == agg.n_streams
     assert res_w.accuracy == agg.accuracy
     assert "slo_gold" in res_w.summary()
+
+
+@pytest.fixture(scope="module")
+def det_models():
+    import jax
+
+    from repro.core.accmodel import AccModel, accmodel_init
+    from repro.vision.dnn import FinalDNN, init_net
+
+    dnn = FinalDNN("detection",
+                   init_net("detection", jax.random.PRNGKey(0), width=8))
+    am = AccModel(accmodel_init(jax.random.PRNGKey(1), 8))
+    return dnn, am
+
+
+def test_chunks_match_legacy_on_detection_fleet(det_models, workload,
+                                                frames):
+    """Detection scores through the vectorized host F1 (no device
+    reduction): ``chunks`` is ChunkResult-for-ChunkResult identical to
+    the per-lane ``legacy`` loop, and ``windowed`` sums the same scores."""
+    assert not det_models[0].supports_device_accuracy
+    res_l = _serve(_engine(det_models, workload, "legacy"), workload,
+                   frames)
+    res_c = _serve(_engine(det_models, workload, "chunks"), workload,
+                   frames)
+    res_w = _serve(_engine(det_models, workload, "windowed"), workload,
+                   frames)
+    assert res_c.stream_ids == res_l.stream_ids
+    for rc, rl in zip(res_c.streams, res_l.streams):
+        assert rc.chunks == rl.chunks
+    chunks = [c for run in res_l.streams for c in run.chunks]
+    assert len(chunks) == workload.stream_chunks
+    # the parity is not vacuous: the scores differ across chunks
+    assert len({c.accuracy for c in chunks}) > 1
+    agg = res_w.aggregate
+    assert agg.n == len(chunks)
+    assert agg.sum_acc == pytest.approx(
+        sum(c.accuracy for c in chunks), rel=1e-12)
 
 
 def test_device_reduce_stays_on_device_and_close(models, workload,
