@@ -89,7 +89,9 @@ class _EngineObs:
     The loop's spans, one of each per chunk interval ``ci``, mark its
     layer boundaries on the host: ``ingest`` (host slice + device put),
     ``dispatch_camera``, ``wait_camera`` (the host blocked on the camera
-    step), ``dispatch_server`` (server, reference and accuracy steps),
+    step), ``dispatch_server`` (server, reference and accuracy steps;
+    args ``server``, ``frames`` and ``precision``, and the
+    ``server_frames_total`` counter by server),
     ``fetch`` (device-to-host copy of the interval's outputs or accuracy
     vector) and ``scoring`` (exactly ``FleetTiming.host_s``, which holds
     the wire bytes' copy). Each ``span`` call reads
@@ -117,6 +119,20 @@ class _EngineObs:
         if self.tracer is not None:
             self.tracer.complete(name, lane, t0, time.perf_counter() - t0,
                                  **args)
+
+    def dispatch_server(self, ci: int, t0: float, dnn, frames: int) -> None:
+        """The server steps of interval ``ci`` are dispatched: a span
+        from ``t0`` naming the server (its DNN's ``name``), the
+        ``frames`` its passes take and its precision; the frames are
+        counted by server."""
+        if self.tracer is not None:
+            self.tracer.complete("dispatch_server", "dispatch", t0,
+                                 time.perf_counter() - t0, ci=ci,
+                                 server=dnn.name, frames=frames,
+                                 precision=dnn.precision_name)
+        if self.reg is not None:
+            self.reg.counter("server_frames_total",
+                             server=dnn.name).inc(frames)
 
     def camera(self, ci: int, t0: float, t_wait: float, acct: float,
                n_lanes: int, n_active: int) -> None:
@@ -946,7 +962,10 @@ class MultiStreamEngine:
                 acc_dev = None
                 entry = dict(ci=ci, outs=outs, ref_outs=ref_outs)
             if ob is not None:
-                ob.span("dispatch_server", "dispatch", t1, ci=ci)
+                # D(H) in the loop is a second pass over the frames
+                passes = 1 if refs is not None else 2
+                ob.dispatch_server(ci, t1, self.final_dnn,
+                                   passes * decoded.shape[0] * cs)
             entry.update(pbytes=pbytes, cam_dt=acct_dt,
                          server_steady_s=server_steady_s,
                          knobs=knobs_used)
@@ -1268,7 +1287,10 @@ class MultiStreamEngine:
                 entry = dict(ci=ci, ids=ids, outs=outs,
                              ref_outs=ref_outs)
             if ob is not None:
-                ob.span("dispatch_server", "dispatch", t1, ci=ci)
+                # D(H) in the loop is a second pass over the frames
+                passes = 1 if refs is not None else 2
+                ob.dispatch_server(ci, t1, self.final_dnn,
+                                   passes * decoded.shape[0] * cs)
             entry.update(pbytes=pbytes, cam_dt=acct_dt,
                          server_steady_s=server_steady_s,
                          knobs=knobs_used)

@@ -160,16 +160,40 @@ def stream_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P(STREAM_AXIS))
 
 
+class BoundStep:
+    """A jitted step whose leading arguments are bound: ``step(*rest)``
+    calls ``jitted(*bound, *rest)``, and ``lower`` and ``_cache_size``
+    are the jitted function's on the same arguments."""
+
+    def __init__(self, jitted, *bound):
+        self.jitted, self.bound = jitted, bound
+
+    def __call__(self, *rest):
+        return self.jitted(*self.bound, *rest)
+
+    def lower(self, *rest):
+        return self.jitted.lower(*self.bound, *rest)
+
+    def _cache_size(self) -> int:
+        return self.jitted._cache_size()
+
+
 def make_server_fleet_step(final_dnn, mesh: Mesh = None):
     """Batch the server-side DNN across streams.
 
     Returns ``server(decoded (N, T, H, W, C)) -> pytree of (N, T, ...)``
-    dense outputs — ONE jitted apply over the flattened (N*T) frame batch
-    instead of the N per-stream ``final_dnn.predict`` Python calls the
-    fleet engine used to make. The engine double-buffers this against the
-    next chunk's camera step (dispatching it asynchronously before the
-    host-side accuracy decode of the previous chunk), so server inference
-    overlaps camera encode.
+    dense outputs — ONE jitted apply (``final_dnn.apply``, the DNN's own
+    forward pass at its stated precision) over the flattened (N*T) frame
+    batch instead of the N per-stream ``final_dnn.predict`` Python calls
+    the fleet engine used to make. The engine double-buffers this
+    against the next chunk's camera step (dispatching it asynchronously
+    before the host-side accuracy decode of the previous chunk), so
+    server inference overlaps camera encode.
+
+    The parameters are arguments of the jitted program, placed on the
+    device once here (replicated over the stream mesh where there is
+    one), not constants compiled into it: new weights reuse the program,
+    and a large model's weights are not folded into its executable.
 
     ``mesh``: optional ``"stream"`` mesh; shards the stream axis with
     shard_map like the camera step (the backbone is per-frame, so the
@@ -177,17 +201,17 @@ def make_server_fleet_step(final_dnn, mesh: Mesh = None):
     """
     from repro.distributed.mesh import STREAM_AXIS
     from repro.distributed.sharding import assert_addressable_mesh
-    from repro.vision.dnn import apply_net, detection_keep_heat
+    from repro.vision.dnn import detection_keep_heat
 
     if mesh is not None:
         assert_addressable_mesh(mesh, "make_server_fleet_step")
 
-    task, params = final_dnn.task, final_dnn.params
+    task = final_dnn.task
 
-    def _server(decoded):
+    def _server(params, decoded):
         N, T = decoded.shape[:2]
         flat = decoded.reshape((N * T,) + decoded.shape[2:])
-        out = apply_net(task, params, flat)
+        out = final_dnn.apply(params, flat)
         if task == "detection":
             # fold the NMS device half of detection decoding into the
             # batched program: the host-side decode is then numpy-only and
@@ -197,10 +221,13 @@ def make_server_fleet_step(final_dnn, mesh: Mesh = None):
             lambda v: v.reshape((N, T) + v.shape[1:]), out)
 
     if mesh is None:
-        return jax.jit(_server)
+        params = jax.device_put(final_dnn.params)
+        return BoundStep(jax.jit(_server), params)
     spec = P(STREAM_AXIS)
-    sharded = shard_map(_server, mesh, in_specs=spec, out_specs=spec)
-    return jax.jit(sharded)
+    params = jax.device_put(final_dnn.params, NamedSharding(mesh, P()))
+    sharded = shard_map(_server, mesh, in_specs=(P(), spec),
+                        out_specs=spec)
+    return BoundStep(jax.jit(sharded), params)
 
 
 def make_accuracy_reduce_step(final_dnn, mesh: Mesh = None):

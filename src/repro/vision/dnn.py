@@ -33,11 +33,11 @@ def conv_init(key, kh, kw, ci, co, scale=None):
     }
 
 
-def conv(p, x, stride=1, groups=1):
+def conv(p, x, stride=1, groups=1, precision=None):
     y = jax.lax.conv_general_dilated(
         x, p["w"], window_strides=(stride, stride), padding="SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        feature_group_count=groups)
+        feature_group_count=groups, precision=precision)
     return y + p["b"]
 
 
@@ -46,16 +46,16 @@ def dw_sep_init(key, ci, co):
     return {"dw": conv_init(k1, 3, 3, 1, ci), "pw": conv_init(k2, 1, 1, ci, co)}
 
 
-def dw_sep(p, x, stride=1):
+def dw_sep(p, x, stride=1, precision=None):
     ci = x.shape[-1]
-    dw = {"w": jnp.tile(p["dw"]["w"], (1, 1, 1, 1)), "b": p["dw"]["b"]}
     # depthwise: HWIO with I=1, groups=ci
     y = jax.lax.conv_general_dilated(
-        x, jnp.transpose(p["dw"]["w"], (0, 1, 2, 3)).reshape(3, 3, 1, ci),
+        x, p["dw"]["w"].reshape(3, 3, 1, ci),
         window_strides=(stride, stride), padding="SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"), feature_group_count=ci)
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), feature_group_count=ci,
+        precision=precision)
     y = jax.nn.relu(y + p["dw"]["b"])
-    return jax.nn.relu(conv(p["pw"], y))
+    return jax.nn.relu(conv(p["pw"], y, precision=precision))
 
 
 def backbone_init(key, width=32):
@@ -69,13 +69,13 @@ def backbone_init(key, width=32):
     }
 
 
-def backbone(p, x):
+def backbone(p, x, precision=None):
     """(B, H, W, 3) -> (B, H/8, W/8, 3*width)."""
-    x = jax.nn.relu(conv(p["stem"], x, stride=2))
-    x = dw_sep(p["b1"], x, stride=2)
-    x = dw_sep(p["b2"], x, stride=2)
-    x = dw_sep(p["b3"], x, stride=1)
-    x = dw_sep(p["b4"], x, stride=1)
+    x = jax.nn.relu(conv(p["stem"], x, stride=2, precision=precision))
+    x = dw_sep(p["b1"], x, stride=2, precision=precision)
+    x = dw_sep(p["b2"], x, stride=2, precision=precision)
+    x = dw_sep(p["b3"], x, stride=1, precision=precision)
+    x = dw_sep(p["b4"], x, stride=1, precision=precision)
     return x
 
 
@@ -84,8 +84,9 @@ def head_init(key, ci, cout):
     return {"c1": conv_init(k1, 3, 3, ci, 64), "c2": conv_init(k2, 1, 1, 64, cout)}
 
 
-def head(p, x):
-    return conv(p["c2"], jax.nn.relu(conv(p["c1"], x)))
+def head(p, x, precision=None):
+    return conv(p["c2"], jax.nn.relu(conv(p["c1"], x, precision=precision)),
+                precision=precision)
 
 
 # ---------------------------------------------------------------------------
@@ -109,15 +110,14 @@ def init_net(task: str, key, width=32):
     return p
 
 
-def apply_net(task: str, params, frames):
-    """frames (B, H, W, 3) -> dict of dense outputs at stride 8."""
-    f = backbone(params["backbone"], frames)
-    if task == "detection":
-        return {"heat": head(params["heat"], f), "wh": head(params["wh"], f),
-                "off": head(params["off"], f)}
-    if task == "segmentation":
-        return {"seg": head(params["seg"], f)}
-    return {"kp": head(params["kp"], f)}
+def apply_net(task: str, params, frames, precision=None):
+    """frames (B, H, W, 3) -> dict of dense outputs at stride 8, every
+    convolution at ``precision`` (None: JAX's default matmul
+    precision)."""
+    f = backbone(params["backbone"], frames, precision)
+    heads = {"detection": ("heat", "wh", "off"), "segmentation": ("seg",),
+             "keypoint": ("kp",)}[task]
+    return {k: head(params[k], f, precision) for k in heads}
 
 
 # ---------------------------------------------------------------------------
@@ -257,16 +257,34 @@ def detection_f1(dets, refs, iou_thresh=0.5):
     return float(np.mean(f1s)) if f1s else 1.0
 
 
+def _class_counts(a, b, n_classes):
+    """Pixels of each class's intersection and union in two stacks of
+    label maps, row by row: (R, ...) int maps -> two (R, C) int64."""
+    rows = a.shape[0]
+    idx_a = (np.arange(rows).reshape((rows,) + (1,) * (a.ndim - 1))
+             * n_classes + a)
+    idx_b = idx_a - a + b
+    count = lambda idx: np.bincount(idx.ravel(), minlength=rows
+                                    * n_classes).reshape(rows, n_classes)
+    inter = count(idx_a[a == b])
+    return inter, count(idx_a) + count(idx_b) - inter
+
+
+def _mean_iou(inter, union) -> float:
+    """Mean IoU over every class present in either map (1.0 where no
+    class is)."""
+    present = union > 0
+    if not present.any():
+        return 1.0
+    return float(np.mean(inter[present] / union[present]))
+
+
 def segmentation_iou(out, ref_out):
-    a = np.asarray(jnp.argmax(out["seg"], -1))
-    b = np.asarray(jnp.argmax(ref_out["seg"], -1))
-    ious = []
-    for cls in (0, 1):
-        inter = np.logical_and(a == cls, b == cls).sum()
-        union = np.logical_or(a == cls, b == cls).sum()
-        if union > 0:
-            ious.append(inter / union)
-    return float(np.mean(ious)) if ious else 1.0
+    """Mean IoU of the two argmax label maps over every class present in
+    either."""
+    return float(segmentation_iou_batched(
+        {"seg": np.asarray(out["seg"])[None]},
+        {"seg": np.asarray(ref_out["seg"])[None]})[0])
 
 
 def keypoint_accuracy(out, ref_out, radius=2.0):
@@ -399,23 +417,15 @@ def detection_f1_batched(out, ref_out, iou_thresh=0.5):
 
 
 def segmentation_iou_batched(out, ref_out):
-    """Per-lane segmentation IoU for (N, T, hs, ws, C) trees -> (N,)."""
-    a = np.asarray(jnp.argmax(out["seg"], -1))      # (N, T, hs, ws)
-    b = np.asarray(jnp.argmax(ref_out["seg"], -1))
-    axes = tuple(range(1, a.ndim))
-    lanes = []
-    for cls in (0, 1):
-        inter = np.logical_and(a == cls, b == cls).sum(axis=axes)
-        union = np.logical_or(a == cls, b == cls).sum(axis=axes)
-        lanes.append((inter, union))
-    out_acc = np.empty(a.shape[0], np.float64)
-    for i in range(a.shape[0]):
-        # same short list + np.mean the per-lane path builds, so the
-        # (at most 2-term) summation order is identical
-        ious = [inter[i] / union[i] for inter, union in lanes
-                if union[i] > 0]
-        out_acc[i] = float(np.mean(ious)) if ious else 1.0
-    return out_acc
+    """Per-lane segmentation IoU for (N, T, hs, ws, C) trees -> (N,):
+    each lane's mean IoU over every class in either label map, the
+    classes' counts in one numpy pass."""
+    seg = np.asarray(out["seg"])
+    a = np.argmax(seg, -1)                            # (N, T, hs, ws)
+    b = np.argmax(np.asarray(ref_out["seg"]), -1)
+    inter, union = _class_counts(a, b, seg.shape[-1])
+    return np.array([_mean_iou(i, u) for i, u in zip(inter, union)],
+                    np.float64)
 
 
 def keypoint_accuracy_batched(out, ref_out, radius=2.0):
@@ -448,7 +458,7 @@ def device_lane_accuracy(task, out, ref_out):
         axes = tuple(range(1, a.ndim))
         iou_sum = jnp.zeros(a.shape[0], jnp.float32)
         n_valid = jnp.zeros(a.shape[0], jnp.float32)
-        for cls in (0, 1):
+        for cls in range(out["seg"].shape[-1]):
             inter = ((a == cls) & (b == cls)).sum(axis=axes)
             union = ((a == cls) | (b == cls)).sum(axis=axes)
             valid = union > 0
@@ -476,23 +486,48 @@ def device_lane_accuracy(task, out, ref_out):
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass
 class FinalDNN:
+    """A server DNN: its task, its parameters, and the forward pass and
+    convolution precision of its architecture. ``forward(params, frames,
+    precision)`` defaults to the repository's convnet (``apply_net`` for
+    the task), ``precision`` (a ``lax.Precision``) to JAX's default
+    matmul precision; ``name`` labels the server in spans and
+    counters."""
     task: str
     params: dict
-    name: str = "final-dnn"
+    name: str = "convnet"
+    forward: Optional[Callable] = None
+    precision: Optional[jax.lax.Precision] = None
+
+    def __post_init__(self):
+        if self.forward is None:
+            self.forward = functools.partial(apply_net, self.task)
+
+    def apply(self, params, frames):
+        """The forward pass on ``params``, which the fleet steps pass as
+        arguments of their jitted programs."""
+        return self.forward(params, frames, self.precision)
+
+    @property
+    def precision_name(self) -> str:
+        """The convolutions' precision: the stated one, else JAX's
+        default matmul precision at the time of the call."""
+        if self.precision is not None:
+            return self.precision.name.lower()
+        return str(jax.config.jax_default_matmul_precision or "default")
 
     def __call__(self, frames):
-        return apply_net(self.task, self.params, frames)
+        return self.apply(self.params, frames)
 
     @functools.cached_property
     def _jit_apply(self):
-        return jax.jit(lambda f: apply_net(self.task, self.params, f))
+        return jax.jit(self.apply)
 
     def predict(self, frames):
-        return self._jit_apply(frames)
+        return self._jit_apply(self.params, frames)
 
     # differentiable proxy of Acc(D(X); D(H)) — fn.15 of the paper
     def proxy_loss(self, frames, ref_out):
-        out = apply_net(self.task, self.params, frames)
+        out = self(frames)
         if self.task == "detection":
             ph = jax.nn.sigmoid(jax.lax.stop_gradient(ref_out["heat"]))
             p = jax.nn.sigmoid(out["heat"])

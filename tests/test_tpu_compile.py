@@ -110,3 +110,31 @@ def test_fused_camera_step_compiles_720p(one_chip, monkeypatch, impl):
     # one 10-frame 720p f32 chunk is 110.6 MB per stream; the step must
     # fit a 16 GB chip with room for the server step and a second chunk
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 4e9
+
+
+def test_fcn_server_step_compiles_720p(one_chip):
+    """The FCN-ResNet101 server step for four 720p streams (40 frames, in
+    blocks of ``vision.fcn.FRAME_BLOCK``) at one bf16 pass a convolution:
+    its temporaries stay under the camera step's at four streams (7.08 GB),
+    so the server does not set the chip's peak."""
+    import sys
+    from pathlib import Path
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench.servers import fcn_resnet101
+    from repro.serve.steps import make_server_fleet_step
+    from repro.vision import fcn
+
+    cfg = {"height": H, "width": W}
+    params = jax.jit(lambda k: fcn_resnet101.init(k, cfg))(jax.random.key(0))
+    step = make_server_fleet_step(fcn.final_dnn(params, "default"))
+    on_chip = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        params)
+    compiled = step.jitted.lower(on_chip,
+                                 _spec((4, T, H, W, 3), one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 7.08e9
+    assert mem.argument_size_in_bytes < 0.21e9 + 4 * T * H * W * 3 * 4 + 1e6
