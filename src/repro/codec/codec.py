@@ -324,10 +324,11 @@ def encode_chunk_pallas(frames: jnp.ndarray, qp_maps: jnp.ndarray):
 def encode_chunk_fused_backend(frames: jnp.ndarray, qp_maps: jnp.ndarray):
     """The fused camera fast-path (``kernels/mbcodec`` chunk kernel).
 
-    One ``mbcodec_chunk_pallas`` call encodes the whole chunk: grid
-    ``(n_tiles, T)`` with the frame axis innermost, the decoded P-frame
-    reference carried in VMEM scratch across the scan, and the per-frame
-    block DMA double-buffered against compute — quantize, entropy bits,
+    One ``mbcodec_chunk_pallas`` call encodes the whole chunk from the
+    frames' planar layout: grid ``(C, H/R, T)`` over R-row stripes with
+    the frame axis innermost, the P-frame reference carried in VMEM
+    scratch across the scan, and the per-frame stripe DMA
+    double-buffered against compute — quantize, entropy bits,
     and reconstruction never leave VMEM between frames. Clip semantics
     match ``fast`` (one decode-time clip); use ``fused_exact`` for the
     per-step reference clip. Off-TPU this lowers to the shared-map

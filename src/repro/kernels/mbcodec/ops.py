@@ -4,7 +4,9 @@ Selects the Pallas kernel on TPU, interpret-mode Pallas for validation, or
 the jnp reference elsewhere. The frame-level wrapper handles blockify /
 padding / per-channel layout so callers never see kernel tiling; the
 chunk-level wrappers (``encode_chunk_fused`` / ``encode_chunk_fused_scores``,
-the registry's ``fused`` / ``fused_exact`` backends) additionally own the
+the registry's ``fused`` / ``fused_exact`` backends) hand the kernel the
+frames' planar view (a bitcast on the TPU), per-stripe QP or score rows,
+and add the per-macroblock headers to its lane bits; they also own the
 off-TPU substitution: the kernel's VMEM-carried chunk scan lowers to the
 shared-map coefficient-space XLA scan on CPU hosts, announced by a one-time
 ``RuntimeWarning`` naming the substituted backend.
@@ -20,9 +22,9 @@ import jax.numpy as jnp
 from repro.codec.codec import (BLOCK_OVERHEAD, block_bits, encode_chunk_fast)
 from repro.codec.dct import (MB, blockify, dct2, freq_weight, idct2, qstep,
                              unblockify)
-from repro.kernels.mbcodec.kernel import (TILE, mbcodec_chunk_pallas,
+from repro.kernels.mbcodec.kernel import (LANES, TILE, mbcodec_chunk_pallas,
                                           mbcodec_chunk_scores_pallas,
-                                          mbcodec_pallas)
+                                          mbcodec_pallas, stripe_rows)
 from repro.kernels.mbcodec.ref import mbcodec_ref
 
 #: backends that already warned about their off-TPU substitution this
@@ -65,10 +67,14 @@ def warn_fallback(name: str, substitute: str) -> None:
         f"kernel.", RuntimeWarning, stacklevel=3)
 
 
-def _pad_lanes(x, value):
-    """Pad the last (macroblock) axis up to a TILE multiple."""
-    pad = [(0, 0)] * (x.ndim - 1) + [(0, (-x.shape[-1]) % TILE)]
-    return jnp.pad(x, pad, constant_values=value)
+def _pad_lanes(x, value, multiple=TILE):
+    """Pad the last (lane) axis up to a ``multiple``; a no-op where it
+    is one already."""
+    pad = (-x.shape[-1]) % multiple
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)],
+                   constant_values=value)
 
 
 def mbcodec(blocks: jnp.ndarray, qp: jnp.ndarray, impl: str = "auto"):
@@ -110,34 +116,32 @@ def encode_frame_fused(frame: jnp.ndarray, qp_map: jnp.ndarray,
 # ---------------------------------------------------------------------------
 # chunk-fused fast-path (registry backends "fused" / "fused_exact")
 # ---------------------------------------------------------------------------
-def _chunk_lanes(frames):
-    """frames (T, H, W, C) -> lane-major blocks (T, 256, n_mb*C), padded
-    to a TILE multiple: column ``mb * C + c`` is macroblock ``mb``'s
-    channel ``c`` flattened row-major (blockify's order). Returns
-    (blocks, n_real, n_mb)."""
-    T, H, W, C = frames.shape
-    x = frames.reshape(T, H // MB, MB, W // MB, MB, C)
-    x = x.transpose(0, 2, 4, 1, 3, 5).reshape(T, MB * MB, -1)
-    n = x.shape[-1]
-    return _pad_lanes(x, 0.0), n, n // C
+def _planes(frames):
+    """frames (T, H, W, C) -> planar (T, C, H, lanes), W padded to a
+    multiple of 128 lanes only where it is not one. Under the TPU's
+    default layout for a 3-channel frame batch (W on lanes) the move is a
+    bitcast."""
+    return _pad_lanes(jnp.moveaxis(frames, -1, 1), 0.0, LANES)
 
 
-def _chunk_finish(rec, bits, n, n_mb, H, W, clip_refs):
-    """Kernel outputs (T, 256 | 1, n+pad) -> (decoded (T, H, W, C),
-    bytes (T,)).
+def _stripe_rows(per_mb, H, value):
+    """Per-macroblock values (..., H/16, W/16) -> the kernel's per-stripe
+    rows (..., H/R, R/16, lanes): each macroblock's value on its 16 lanes,
+    padded lanes set to ``value``."""
+    x = _pad_lanes(jnp.repeat(per_mb, MB, axis=-1), value, LANES)
+    R = stripe_rows(H)
+    return x.reshape(x.shape[:-2] + (H // R, R // MB, x.shape[-1]))
 
-    Channel bits re-merge to one header per macroblock (codec.block_bits
-    charges BLOCK_OVERHEAD once per block, the kernel once per channel
-    column)."""
-    T = rec.shape[0]
-    C = n // n_mb
-    decoded = rec[:, :, :n].reshape(T, MB, MB, H // MB, W // MB, C)
-    decoded = decoded.transpose(0, 3, 1, 4, 2, 5).reshape(T, H, W, C)
-    bits_mb = bits[:, 0, :n].reshape(T, n_mb, C).sum(-1) \
-        - (C - 1) * BLOCK_OVERHEAD
-    pbytes = bits_mb.sum(-1) / 8.0
-    if not clip_refs:  # exact path already clipped every reference in-VMEM
-        decoded = jnp.clip(decoded, 0.0, 1.0)
+
+def _chunk_finish(dec, bits, W):
+    """Kernel outputs -> (decoded (T, H, W, C), bytes (T,)). The kernel
+    sums each lane's coefficient bits; codec.block_bits charges
+    BLOCK_OVERHEAD once per macroblock, added here. Padded lanes hold
+    zeros and add no bits."""
+    T, _, H, _ = dec.shape
+    decoded = jnp.moveaxis(dec[..., :W], 1, -1)
+    mb_bits = bits[..., :W].reshape(T, -1, MB).sum(-1)
+    pbytes = (mb_bits.sum(-1) + (H // MB) * (W // MB) * BLOCK_OVERHEAD) / 8.0
     return decoded, pbytes
 
 
@@ -187,7 +191,6 @@ def encode_chunk_fused(frames: jnp.ndarray, qp_maps: jnp.ndarray,
     Off-TPU it lowers to the shared-map coefficient-space XLA scan
     (``warn_fallback`` announces the substitution once).
     """
-    T = frames.shape[0]
     H, W = frames.shape[1], frames.shape[2]
     if impl == "auto":
         impl = "pallas" if on_tpu() else "xla"
@@ -197,13 +200,10 @@ def encode_chunk_fused(frames: jnp.ndarray, qp_maps: jnp.ndarray,
             "the clip-corrected XLA scan (fast_exact)" if clip_refs
             else "the shared-map coefficient-space XLA scan (fast family)")
         return _encode_chunk_fused_xla(frames, qp_maps, clip_refs)
-    blocks, n, n_mb = _chunk_lanes(frames)
-    qp = jnp.broadcast_to(qp_maps.reshape(qp_maps.shape[0], -1), (T, n_mb))
-    qp = jnp.repeat(qp, n // n_mb, axis=1)       # columns are (mb, C) flat
-    qp = _pad_lanes(qp.reshape(T, 1, n), 30.0)
-    rec, bits = mbcodec_chunk_pallas(blocks, qp, clip_refs=clip_refs,
-                                     interpret=(impl == "interpret"))
-    return _chunk_finish(rec, bits, n, n_mb, H, W, clip_refs)
+    dec, bits = mbcodec_chunk_pallas(
+        _planes(frames), _stripe_rows(qp_maps.astype(jnp.float32), H, 30.0),
+        clip_refs=clip_refs, interpret=(impl == "interpret"))
+    return _chunk_finish(dec, bits, W)
 
 
 def encode_chunk_fused_scores(frames: jnp.ndarray, pooled: jnp.ndarray,
@@ -230,11 +230,9 @@ def encode_chunk_fused_scores(frames: jnp.ndarray, pooled: jnp.ndarray,
             else "the shared-map coefficient-space XLA scan (fast family)")
         qp_map = jnp.where(pooled >= knobs[0], knobs[1], knobs[2])[None]
         return _encode_chunk_fused_xla(frames, qp_map, clip_refs)
-    blocks, n, n_mb = _chunk_lanes(frames)
-    p = jnp.repeat(pooled.reshape(1, -1), n // n_mb, axis=1)
     # padded lanes score -inf: always the low-quality level
-    p = _pad_lanes(p.astype(jnp.float32), -jnp.inf)
-    rec, bits = mbcodec_chunk_scores_pallas(
-        blocks, p, knobs[:3].astype(jnp.float32), clip_refs=clip_refs,
-        interpret=(impl == "interpret"))
-    return _chunk_finish(rec, bits, n, n_mb, H, W, clip_refs)
+    rows = _stripe_rows(pooled.astype(jnp.float32), H, -jnp.inf)
+    dec, bits = mbcodec_chunk_scores_pallas(
+        _planes(frames), rows, knobs[:3].astype(jnp.float32),
+        clip_refs=clip_refs, interpret=(impl == "interpret"))
+    return _chunk_finish(dec, bits, W)

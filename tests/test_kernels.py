@@ -187,6 +187,46 @@ def test_chunk_fused_all_dropped_frames():
     assert np.all(np.asarray(b_f[1:]) <= 0.05 * float(b_f[0]))
 
 
+@pytest.mark.parametrize("hw", [(48, 208), (96, 160)])
+@pytest.mark.parametrize("clip_refs", [False, True])
+def test_chunk_fused_planar_stripes(hw, clip_refs):
+    """Shapes the planar stripe layout has to adapt to: a width that is no
+    multiple of 128 lanes (48x208 pads to 256) and a height of several
+    row blocks (96x160: two 48-row stripes). The QP-map and the scores
+    variants match the encoder they stand for (``fused_exact`` the exact
+    one, ``fused`` the fast one) and each other, and the padded lanes
+    decode to zero and carry no bits, so they change neither the decoded
+    pixels nor the bytes."""
+    from repro.kernels.mbcodec import ops
+    from repro.kernels.mbcodec.kernel import (mbcodec_chunk_pallas,
+                                              stripe_rows)
+
+    H, W = hw
+    frames = _chunk(T=4, H=H, W=W)
+    scores = jax.random.uniform(jax.random.PRNGKey(7), (H // 16, W // 16))
+    knobs = jnp.array([0.5, 30.0, 42.0], jnp.float32)
+    qmap = jnp.where(scores >= 0.5, 30.0, 42.0)[None]
+    d_r, b_r = (encode_chunk if clip_refs else encode_chunk_fast)(frames,
+                                                                  qmap)
+    d_m, b_m = encode_chunk_fused(frames, qmap, clip_refs=clip_refs,
+                                  impl="interpret")
+    d_s, b_s = encode_chunk_fused_scores(frames, scores, knobs,
+                                         clip_refs=clip_refs,
+                                         impl="interpret")
+    np.testing.assert_allclose(np.asarray(d_m), np.asarray(d_r), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(b_m), np.asarray(b_r), rtol=1e-4)
+    np.testing.assert_array_equal(np.asarray(d_s), np.asarray(d_m))
+    np.testing.assert_array_equal(np.asarray(b_s), np.asarray(b_m))
+
+    planes = ops._planes(frames)
+    assert planes.shape == (4, 3, H, -(-W // 128) * 128)
+    assert H // stripe_rows(H) == H // 48
+    dec, bits = mbcodec_chunk_pallas(planes, ops._stripe_rows(qmap, H, 30.0),
+                                     clip_refs=clip_refs, interpret=True)
+    assert not np.any(np.asarray(dec[..., W:]))
+    assert not np.any(np.asarray(bits[..., W:]))
+
+
 def test_chunk_fused_xla_fallback_warns_and_matches_fast():
     """Off-TPU the fused backend substitutes the shared-map XLA scan: it
     must announce the substitution once (RuntimeWarning naming the

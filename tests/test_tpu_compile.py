@@ -8,7 +8,9 @@ cannot show. The topology is described inside a fixture, never at import
 time, so every test worker collects the same tests; this is the only test
 file that describes one.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -58,25 +60,31 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _stripe_rows_shape():
+    """Per-stripe QP or score rows at 720p: (H/R, R/16, W)."""
+    from repro.kernels.mbcodec.kernel import stripe_rows
+
+    R = stripe_rows(H)
+    return (H // R, R // 16, W)
+
+
 @pytest.mark.parametrize("clip_refs", [False, True])
 def test_chunk_scores_kernel_compiles_720p(one_chip, clip_refs):
-    from repro.kernels.mbcodec.kernel import (COEFS,
-                                              mbcodec_chunk_scores_pallas)
+    from repro.kernels.mbcodec.kernel import mbcodec_chunk_scores_pallas
 
-    n = _n_padded()
     compiled = mbcodec_chunk_scores_pallas.lower(
-        _spec((T, COEFS, n), one_chip), _spec((1, n), one_chip),
+        _spec((T, 3, H, W), one_chip), _spec(_stripe_rows_shape(), one_chip),
         _spec((3,), one_chip), clip_refs=clip_refs).compile()
     _assert_kernel(compiled)
 
 
 @pytest.mark.parametrize("clip_refs", [False, True])
 def test_chunk_kernel_compiles_720p(one_chip, clip_refs):
-    from repro.kernels.mbcodec.kernel import COEFS, mbcodec_chunk_pallas
+    from repro.kernels.mbcodec.kernel import mbcodec_chunk_pallas
 
-    n = _n_padded()
     compiled = mbcodec_chunk_pallas.lower(
-        _spec((T, COEFS, n), one_chip), _spec((T, 1, n), one_chip),
+        _spec((T, 3, H, W), one_chip),
+        _spec((T,) + _stripe_rows_shape(), one_chip),
         clip_refs=clip_refs).compile()
     _assert_kernel(compiled)
 
@@ -107,9 +115,25 @@ def test_fused_camera_step_compiles_720p(one_chip, monkeypatch, impl):
     compiled = step.lower(_spec((2, T, H, W, 3), one_chip)).compile()
     _assert_kernel(compiled)
     mem = compiled.memory_analysis()
-    # one 10-frame 720p f32 chunk is 110.6 MB per stream; the step must
-    # fit a 16 GB chip with room for the server step and a second chunk
+    # one 10-frame 720p f32 chunk is 110.6 MB per stream; the kernel reads
+    # the frames where they lie, so the step's temporaries are the
+    # AccModel's and the per-stripe rows', not copies of the frames
+    assert mem.temp_size_in_bytes < 1e9
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 4e9
+    assert _frame_sized_layout_ops(compiled.as_text(), 2 * T * H * W * 3) \
+        == []
+
+
+def _frame_sized_layout_ops(hlo, n):
+    """The copies, transposes, reshapes, pads and slices in compiled HLO
+    text (fused computations included) whose f32 result holds ``n``
+    elements or more."""
+    found = []
+    for m in re.finditer(r"= f32\[([\d,]*)\]\S* (copy|transpose|reshape|"
+                         r"pad|slice)\(", hlo):
+        if math.prod(int(d) for d in m.group(1).split(",") if d) >= n:
+            found.append(m.group(0))
+    return found
 
 
 def test_fcn_server_step_compiles_720p(one_chip):
